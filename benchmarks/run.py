@@ -86,6 +86,9 @@ def main(argv=None) -> None:
     trials = 4000 if args.quick else 20000
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (common, fig3_delays, fig4_vs_load, fig5_ec2,
                    fig6_vs_workers, fig7_vs_target, fig8_convergence,
                    fig9_multimessage, fig10_load_rebalance,

@@ -95,7 +95,13 @@ class TruncatedGaussianDelays(DelayModel):
             z = np.sqrt(self.rho) * w + np.sqrt(1 - self.rho) * e
             t = mu + sigma * z
             return jnp.clip(t, lo, hi)
-        return _truncnorm(key, (trials, n, r), mu, sigma, lo, hi)
+        # The standardized bounds are the same for every worker, so they
+        # stay scalars.  Per-worker bounds would put erf of a constant
+        # vector in the graph, which XLA folds on the host for some loop
+        # shapes and not others: draws then depended on the chunking.
+        z = jax.random.truncated_normal(key, -a / sigma, b / sigma,
+                                        (trials, n, r))
+        return mu + sigma * z
 
     def _sample(self, key, trials, n, r):
         k1, k2 = jax.random.split(key)

@@ -1095,28 +1095,6 @@ def _padded_keys(seed: int, trials: int, padded: int) -> Array:
     return keys
 
 
-def _register_barrier_batching() -> None:
-    """``jax.lax.optimization_barrier`` (used below to pin the within-chunk
-    reduction order) has no vmap batching rule in the jax versions this repo
-    pins, and the device-sharded path vmaps the chunk scan over a leading
-    device axis (``repro.sharding.shard_trials``).  The rule is trivially
-    dimension-preserving — the barrier is a semantic identity — so register
-    it when missing rather than forking the single- and multi-device
-    programs (which would itself break cross-device-count bit-exactness)."""
-    try:
-        from jax.interpreters import batching
-        p = getattr(jax.lax, "optimization_barrier_p", None)
-        if p is not None and p not in batching.primitive_batchers:
-            def rule(args, dims):
-                return p.bind(*args), dims
-            batching.primitive_batchers[p] = rule
-    except Exception:  # pragma: no cover — future-jax defensive
-        pass
-
-
-_register_barrier_batching()
-
-
 def _tree_sum(v: Array) -> Array:
     """Sum over axis 0 through an explicit balanced pairwise tree (zero-pad
     to a power of two, then halve): every add is elementwise, so the f32
@@ -1961,10 +1939,10 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
 
         # NB: the round index rides the scan xs (an ``arange``) instead of
         # an integer carry — numerically identical, and immune to a
-        # multi-device host-mesh miscompilation (observed under
-        # ``shard_map``, see ``repro.sharding.shard_trials``) where XLA
-        # aliases constant-initialized scalar carries across co-resident
-        # shards, so ``t == 0`` misfires on every device but the first.
+        # multi-device host-mesh miscompilation once seen under
+        # ``shard_map`` (JAX 0.4) where XLA aliased constant-initialized
+        # scalar carries across co-resident shards, so ``t == 0``
+        # misfired on every device but the first.
         if censored:
             def body(carry, xs):
                 kr, _ = xs

@@ -20,6 +20,11 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_D = 256
 DEFAULT_BLOCK_B = 256
+# The kernel computes in float32, as its oracle does.  Mosaic's default
+# contraction precision may round f32 operands to bf16 on the MXU; the
+# products here are matrix-vector, bound by reading X, so full precision
+# costs little.
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def default_interpret(*, tpu_only: bool = False) -> bool:
@@ -48,7 +53,8 @@ def _xt_theta_kernel(x_ref, th_ref, u_ref):
 
     x = x_ref[...].astype(jnp.float32)          # (bd, bb)
     th = th_ref[...].astype(jnp.float32)        # (bd, 1)
-    u_ref[...] += jnp.dot(x.T, th, preferred_element_type=jnp.float32)
+    u_ref[...] += jnp.dot(x.T, th, precision=_F32,
+                          preferred_element_type=jnp.float32)
 
 
 def _x_u_kernel(x_ref, u_ref, y_ref):
@@ -61,7 +67,8 @@ def _x_u_kernel(x_ref, u_ref, y_ref):
 
     x = x_ref[...].astype(jnp.float32)          # (bd, bb)
     u = u_ref[...].astype(jnp.float32)          # (bb, 1)
-    y_ref[...] += jnp.dot(x, u, preferred_element_type=jnp.float32)
+    y_ref[...] += jnp.dot(x, u, precision=_F32,
+                          preferred_element_type=jnp.float32)
 
 
 def gram_matvec_pallas(X: jax.Array, theta: jax.Array, *,

@@ -8,12 +8,13 @@ sequential, but with the static coverage-weight matrix
 ``W[p, t] = sum_j gamma**j * [C[p, j] == t]`` each step collapses to
 dense lane-parallel ops over a block of trials:
 
-  scores  = cov @ W^T                 (one MXU matmul per step)
+  scores  = cov @ W^T                 (fixed-order sum over tasks)
   p       = argmin over rows          (min + iota trick, ties -> lowest)
-  cov    += (onehot_p @ W) / e_pick   (one more matmul)
+  cov    += W[p] * (1 / e_pick)       (one-hot select of row p)
 
-so the whole O(n^2 * r) scan becomes n small matmuls on a (block, n)
-trial block held in VMEM — no gathers, no per-trial control flow.
+so the whole O(n^2 * r) scan becomes n steps of lane-parallel vector
+ops on a (block, n) trial block held in VMEM — no gathers, no per-trial
+control flow.
 
 ``greedy_assign_pallas`` is the raw kernel (grid over trial blocks,
 interpret-mode fallback on CPU); ``repro.kernels.ref.greedy_assign_ref``
@@ -31,40 +32,69 @@ from .gram_matvec import resolve_interpret
 DEFAULT_BLOCK_TRIALS = 128
 
 
-def _greedy_kernel(w_ref, order_ref, epick_ref, need_ref, out_ref):
+def coverage_scores(cov: jax.Array, wt_rows) -> jax.Array:
+    """Greedy row scores ``cov @ W.T`` as one fixed-order sum over tasks.
+
+    ``cov`` (B, n) per-task coverage, ``wt_rows`` the n rows of ``W.T``,
+    each (1, n).  The sum runs t = 0, 1, ..., n-1 in that order on the
+    vector unit.  A matmul would leave the order, and on a TPU the
+    precision, to the backend, and the greedy's argmin then breaks ties
+    between rows that are equal in exact arithmetic differently in the
+    Pallas kernel and in its scan twin ``ref.greedy_assign_ref``."""
+    acc = cov[:, 0:1] * wt_rows[0]
+    for t in range(1, len(wt_rows)):
+        acc = acc + cov[:, t:t + 1] * wt_rows[t]
+    return acc
+
+
+def _greedy_kernel(w_ref, wt_ref, order_ref, inv_ref, need_ref, out_ref):
     """One (block, n) trial block: run all n picks to completion.
 
-    Refs: ``w_ref`` (n, n) f32 coverage weights; ``order_ref`` (bt, n)
-    i32 pickers fastest-first; ``epick_ref`` (bt, n) f32 sorted delay
-    estimates (pre-clamped away from zero); ``need_ref`` (bt, n) f32
-    reissue priorities (all-zero = none); ``out_ref`` (bt, n) i32
-    worker-of-row."""
-    W = w_ref[...]
+    Refs: ``w_ref`` (n, n) f32 coverage weights and ``wt_ref`` their
+    transpose; ``order_ref`` (bt, n) i32 pickers fastest-first;
+    ``inv_ref`` (bt, n) f32 reciprocals of the sorted delay estimates;
+    ``need_ref`` (bt, n) f32 reissue priorities (all-zero =
+    none); ``out_ref`` (bt, n) i32 worker-of-row.
+
+    Mosaic lowers no ``dynamic_slice`` and no boolean loop carry, so the
+    pick-``t`` columns are read through a lane mask and ``taken`` is a
+    0/1 float.  Scores use the fixed-order sum of ``coverage_scores``
+    (no MXU dot), the same one the scan twin uses, so scores equal in
+    exact arithmetic compare equal here and there on every backend.  The
+    reciprocals are taken outside the kernel, as the twin takes them, so
+    that the kernel only multiplies (rounded alike everywhere) and never
+    divides (which Mosaic and XLA may lower differently)."""
+    n = w_ref.shape[0]
+    wrows = [w_ref[p:p + 1, :] for p in range(n)]
+    wtrows = [wt_ref[t:t + 1, :] for t in range(n)]
     order = order_ref[...]
-    epick = epick_ref[...]
+    inv_e = inv_ref[...]
     need = need_ref[...]
-    bt, n = order.shape
-    wt = W.T
+    bt = order.shape[0]
     big = jnp.float32(jnp.finfo(jnp.float32).max)
     lanes = jax.lax.broadcasted_iota(jnp.int32, (bt, n), 1)
 
     def pick(t, carry):
         cov, taken, wout = carry
-        scores = jnp.where(taken, big, jnp.dot(cov, wt))
-        pref = jnp.where((need > 0) & ~taken, scores, big)
+        scores = jnp.where(taken > 0, big, coverage_scores(cov, wtrows))
+        pref = jnp.where((need > 0) & (taken == 0), scores, big)
         has = jnp.min(pref, axis=-1, keepdims=True) < big
         sel = jnp.where(has, pref, scores)
         m = jnp.min(sel, axis=-1, keepdims=True)
         p = jnp.min(jnp.where(sel == m, lanes, n), axis=-1, keepdims=True)
         hit = lanes == p                                   # ties -> lowest
-        wid = jax.lax.dynamic_slice_in_dim(order, t, 1, axis=1)
+        col = lanes == t
+        wid = jnp.sum(jnp.where(col, order, 0), axis=-1, keepdims=True)
+        inv_t = jnp.sum(jnp.where(col, inv_e, 0.0), axis=-1, keepdims=True)
         wout = jnp.where(hit, wid, wout)
-        taken = taken | hit
-        e_t = jax.lax.dynamic_slice_in_dim(epick, t, 1, axis=1)
-        cov = cov + jnp.dot(hit.astype(jnp.float32), W) / e_t
+        taken = jnp.where(hit, 1.0, taken)
+        w_p = wrows[0]
+        for q in range(1, n):                             # exact select
+            w_p = jnp.where(p == q, wrows[q], w_p)
+        cov = cov + w_p * inv_t
         return cov, taken, wout
 
-    init = (jnp.zeros((bt, n), jnp.float32), jnp.zeros((bt, n), jnp.bool_),
+    init = (jnp.zeros((bt, n), jnp.float32), jnp.zeros((bt, n), jnp.float32),
             jnp.zeros((bt, n), jnp.int32))
     _, _, wout = jax.lax.fori_loop(0, n, pick, init)
     out_ref[...] = wout
@@ -80,6 +110,8 @@ def greedy_assign_pallas(W: jax.Array, order: jax.Array, epick: jax.Array,
     ``worker_of_row`` (B, n) int32.  ``interpret`` defaults to
     backend-aware: compiled on TPU/GPU, interpreted on CPU."""
     interpret = resolve_interpret(interpret)
+    W = W.astype(jnp.float32)
+    epick = epick.astype(jnp.float32)
     B, n = order.shape
     if need_row is None:
         need_row = jnp.zeros((B, n), jnp.float32)
@@ -97,13 +129,14 @@ def greedy_assign_pallas(W: jax.Array, order: jax.Array, epick: jax.Array,
         _greedy_kernel,
         grid=(Bp // bt,),
         in_specs=[pl.BlockSpec((n, n), lambda i: (0, 0)),
+                  pl.BlockSpec((n, n), lambda i: (0, 0)),
                   pl.BlockSpec((bt, n), lambda i: (i, 0)),
                   pl.BlockSpec((bt, n), lambda i: (i, 0)),
                   pl.BlockSpec((bt, n), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bt, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, n), jnp.int32),
         interpret=interpret,
-    )(W.astype(jnp.float32), order.astype(jnp.int32),
-      epick.astype(jnp.float32), need_row.astype(jnp.float32))
+    )(W, W.T, order.astype(jnp.int32), 1.0 / epick,
+      need_row.astype(jnp.float32))
 
     return out[:B]

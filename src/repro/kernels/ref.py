@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .greedy_assign import coverage_scores
+
 __all__ = ["gram_matvec_ref", "swa_attention_ref", "greedy_assign_ref"]
 
 
@@ -42,7 +44,7 @@ def greedy_assign_ref(W: jax.Array, order: jax.Array, epick: jax.Array,
     ``W`` is the static (n, n) float32 coverage-weight matrix of a TO
     matrix ``C``: ``W[p, t] = sum_j gamma**j * [C[p, j] == t]`` over the
     active slots of row ``p`` — so a row's greedy score is the single
-    matvec ``cov @ W[p]`` and picking row ``p`` adds ``W[p] / e`` to the
+    matvec ``cov @ W[p]`` and picking row ``p`` adds ``W[p] * (1 / e)`` to the
     per-task coverage.  ``order`` (B, n) int32 lists each trial's pickers
     fastest-first; ``epick`` (B, n) float32 the matching sorted delay
     estimates (pre-clamped away from zero); ``need_row`` (B, n), when
@@ -56,10 +58,12 @@ def greedy_assign_ref(W: jax.Array, order: jax.Array, epick: jax.Array,
     W = W.astype(jnp.float32)
     big = jnp.float32(jnp.finfo(jnp.float32).max)
     lanes = jnp.arange(n)[None, :]
+    wt_rows = [W.T[t:t + 1] for t in range(n)]
+    inv_e = 1.0 / epick.astype(jnp.float32)
 
     def pick(carry, t):
         cov, taken, wout = carry
-        scores = jnp.where(taken, big, cov @ W.T)
+        scores = jnp.where(taken, big, coverage_scores(cov, wt_rows))
         if need_row is None:
             sel = scores
         else:
@@ -70,7 +74,7 @@ def greedy_assign_ref(W: jax.Array, order: jax.Array, epick: jax.Array,
         hit = lanes == p[:, None]
         wout = jnp.where(hit, order[:, t][:, None], wout)
         taken = taken | hit
-        cov = cov + jnp.take(W, p, axis=0) / epick[:, t][:, None]
+        cov = cov + jnp.take(W, p, axis=0) * inv_e[:, t][:, None]
         return (cov, taken, wout), None
 
     init = (jnp.zeros((B, n), jnp.float32), jnp.zeros((B, n), bool),

@@ -29,6 +29,7 @@ import sys
 from ..core.delays import ec2_like, scenario1, scenario2
 from ..core.grid import FAMILIES, GridResult, GridSpec, stream_grid
 from ..core.montecarlo import cache_stats
+from ..compile_cache import enable_compile_cache
 
 MODELS = ("scenario1", "scenario2", "ec2")
 
@@ -86,6 +87,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     args = build_parser().parse_args(argv)
     if args.spec is not None:
         with open(args.spec) as fh:

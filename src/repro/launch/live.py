@@ -29,6 +29,7 @@ import json
 from ..core import FAULT_SCENARIOS, RoundConfig, save_trace
 from ..live import Master, listen, run_live, run_worker
 from .train import build_cluster, derive_seeds
+from ..compile_cache import enable_compile_cache
 
 
 def _add_cluster_args(ap: argparse.ArgumentParser) -> None:
@@ -94,6 +95,7 @@ def _finish(result, args) -> None:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="Live async master-worker round execution.")
     sub = ap.add_subparsers(dest="mode", required=True)

@@ -4,17 +4,9 @@ state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from ..sharding import MeshCtx
-
-
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """``jax.sharding.AxisType`` only exists in newer jax; older releases
-    treat every axis as Auto already, so omit the kwarg there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +14,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     (2 x 16 x 16 = 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_ctx(*, multi_pod: bool = False) -> MeshCtx:
@@ -34,5 +26,5 @@ def make_mesh_ctx(*, multi_pod: bool = False) -> MeshCtx:
 def make_local_mesh_ctx(data: int = 1, model: int = 1) -> MeshCtx:
     """Small mesh over however many devices exist (tests)."""
     mesh = jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_types_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
     return MeshCtx(mesh=mesh, data_axes=("data",), model_axis="model")

@@ -27,6 +27,7 @@ import sys
 from ..core.grid import FAMILIES, GridSpec
 from ..core.planner import plan
 from .grid import MODELS, _axis, _build_model
+from ..compile_cache import enable_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -71,6 +72,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     args = build_parser().parse_args(argv)
     if args.spec is not None:
         with open(args.spec) as fh:

@@ -17,9 +17,11 @@ import numpy as np
 from ..configs import ARCH_IDS, get_config
 from ..models import forward, init_cache, init_params
 from ..train import make_serve_step
+from ..compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -38,6 +38,7 @@ from ..optim import adamw, cosine_schedule
 from ..sharding import mesh_context
 from ..train import init_train_state, make_straggler_train_step
 from ..ckpt import save_checkpoint, load_checkpoint, latest_checkpoint
+from ..compile_cache import enable_compile_cache
 from .mesh import make_mesh_ctx
 
 
@@ -96,7 +97,42 @@ def build_cluster(args, seeds):
     return delay
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainRun:
+    """What ``build_run`` sets up for the training loop."""
+    spec: object                # the round's RoundSpec
+    part: TaskPartition
+    state: object               # TrainState, resumed or fresh
+    start: int                  # first step to run
+    step_fn: object             # jitted straggler train step
+    base_C: np.ndarray          # the round's TO matrix
+    sched: AdaptiveScheduler | None
+
+
+def straggler_rounds(run: TrainRun, delay_root, steps: int, *,
+                     reissue: bool = False):
+    """The training loop from ``run.start``, one straggler round per SGD
+    step: build the round's slot batches from the (adaptively permuted)
+    TO matrix, take the step, and feed the round's observed delays back
+    to ``run.sched`` (``None`` for a static schedule).  ``reissue`` gives
+    the tasks a round left undelivered re-gather priority in the next.
+    Yields ``(step, state, metrics)`` after every step."""
+    state, sched, cluster = run.state, run.sched, None
+    for i in range(run.start, steps):
+        C = run.base_C if sched is None else sched.matrix()
+        row = None if sched is None else jnp.asarray(sched.row_of_worker())
+        toks, labs = lm_task_batches(run.part, C, i)
+        state, m, cluster = run.step_fn(state, toks, labs,
+                                        jax.random.fold_in(delay_root, i),
+                                        cluster, row)
+        if sched is not None:
+            sched.observe(np.asarray(m["worker_t1"]))
+            if reissue:
+                sched.set_need(~np.asarray(m["delivered_tasks"]))
+        yield i, state, m
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         description="Straggler-scheduled training with record/replay "
                     "delay sources.",
@@ -184,7 +220,71 @@ def main(argv=None):
                     choices=("local", "pod", "multipod"))
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--resume", action="store_true")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def round_config(args, seeds) -> RoundConfig:
+    """The run's one validated round description, from ``--config`` or
+    from the round flags (``args`` is updated to match a loaded
+    document).  Raises ``ValueError`` on an invalid round."""
+    if args.config:
+        rc = RoundConfig.load(args.config)
+        args.n, args.k, args.schedule = rc.n, rc.k, rc.kind
+        args.r = rc.width
+        args.adaptive = rc.adaptive
+        args.deadline = rc.deadline
+        args.deadline_policy = rc.deadline_policy
+        args.dead_after = rc.dead_after
+        return rc
+    loads = (tuple(int(v) for v in args.loads.split(","))
+             if args.loads else None)
+    return RoundConfig(
+        n=args.n, k=args.k, kind=args.schedule,
+        r=args.n if args.schedule == "ra" else args.r, loads=loads,
+        deadline=args.deadline, deadline_policy=args.deadline_policy,
+        adaptive=args.adaptive, dead_after=args.dead_after,
+        seed=seeds["schedule_seed"])
+
+
+def build_run(args, cfg, seeds, rc: RoundConfig) -> TrainRun:
+    """The launcher's set-up for model ``cfg`` under round ``rc``: the
+    task partition, AdamW on the cosine schedule, the train state (resumed
+    from the newest checkpoint under ``--resume``), the delay source, the
+    jitted straggler step and, with ``--adaptive``, the scheduler.  Call
+    it inside the run's mesh context."""
+    spec = rc.to_round_spec()
+    delay = build_cluster(args, seeds)
+    part = TaskPartition(n=args.n, global_batch=args.batch,
+                         seq_len=args.seq, vocab=cfg.vocab_size,
+                         source="bigram", seed=seeds["data_seed"])
+    opt = adamw(cosine_schedule(args.lr, args.steps, warmup=5))
+    state = init_train_state(seeds["init_key"], cfg, opt)
+    start = 0
+    if args.resume and args.ckpt_dir:
+        path = latest_checkpoint(args.ckpt_dir, args.arch)
+        if path:
+            state = load_checkpoint(path, state)
+            start = int(state.step)
+            print(f"resumed from {path} at step {start}")
+    if isinstance(delay, TraceProcess) and start:
+        # resumed runs keep their remaining steps aligned with the
+        # trace rounds those steps originally consumed
+        delay = dataclasses.replace(delay, start_round=start)
+    if hasattr(delay, "check_rounds"):
+        # fail fast (with the remedy) instead of r rounds into the run
+        delay.check_rounds(args.steps - start)
+    step_fn = jax.jit(make_straggler_train_step(cfg, opt, spec, delay))
+    base_C = spec.to_matrix()
+    sched_kw = ({} if args.dead_after is None
+                else {"dead_after": args.dead_after, "target_k": spec.k})
+    sched = (AdaptiveScheduler(base_C, **sched_kw)
+             if args.adaptive else None)
+    return TrainRun(spec, part, state, start, step_fn, base_C, sched)
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -211,42 +311,14 @@ def main(argv=None):
     # (k/r ranges, ragged coverage, deadline/policy pairing, the adaptive-
     # family cross-field rules) whether it came from flags or --config.
     try:
-        if args.config:
-            rc = RoundConfig.load(args.config)
-            args.n, args.k, args.schedule = rc.n, rc.k, rc.kind
-            args.r = rc.width
-            args.adaptive = rc.adaptive
-            args.deadline = rc.deadline
-            args.deadline_policy = rc.deadline_policy
-            args.dead_after = rc.dead_after
-            loads = rc.loads
-        else:
-            loads = (tuple(int(v) for v in args.loads.split(","))
-                     if args.loads else None)
-            rc = RoundConfig(
-                n=args.n, k=args.k, kind=args.schedule,
-                r=args.n if args.schedule == "ra" else args.r, loads=loads,
-                deadline=args.deadline, deadline_policy=args.deadline_policy,
-                adaptive=args.adaptive, dead_after=args.dead_after,
-                seed=seeds["schedule_seed"])
+        rc = round_config(args, seeds)
     except ValueError as e:
         raise SystemExit(str(e))
-    spec = rc.to_round_spec()
-    delay = build_cluster(args, seeds)
-    part = TaskPartition(n=args.n, global_batch=args.batch,
-                         seq_len=args.seq, vocab=cfg.vocab_size,
-                         source="bigram", seed=seeds["data_seed"])
-    opt = adamw(cosine_schedule(args.lr, args.steps, warmup=5))
+    loads = rc.loads
 
     with mesh_context(ctx):
-        state = init_train_state(seeds["init_key"], cfg, opt)
-        start = 0
-        if args.resume and args.ckpt_dir:
-            path = latest_checkpoint(args.ckpt_dir, args.arch)
-            if path:
-                state = load_checkpoint(path, state)
-                start = int(state.step)
-                print(f"resumed from {path} at step {start}")
+        run = build_run(args, cfg, seeds, rc)
+        spec, start, state = run.spec, run.start, run.state
         print(f"{cfg.name}: {num_params(state.params):,} params | "
               f"round n={spec.n} r={spec.r} k={spec.k} {args.schedule}"
               f"{'+adaptive' if args.adaptive else ''}"
@@ -254,38 +326,14 @@ def main(argv=None):
               f"cluster {args.cluster}"
               f"{' +' + args.scenario if args.scenario != 'none' else ''}"
               f"{f' deadline={args.deadline:g}/{args.deadline_policy}' if args.deadline is not None else ''}")
-        if isinstance(delay, TraceProcess) and start:
-            # resumed runs keep their remaining steps aligned with the
-            # trace rounds those steps originally consumed
-            delay = dataclasses.replace(delay, start_round=start)
-        if hasattr(delay, "check_rounds"):
-            # fail fast (with the remedy) instead of r rounds into the run
-            delay.check_rounds(args.steps - start)
-        step_fn = jax.jit(make_straggler_train_step(cfg, opt, spec, delay))
-        base_C = spec.to_matrix()
-        sched_kw = ({} if args.dead_after is None
-                    else {"dead_after": args.dead_after, "target_k": spec.k})
-        sched = (AdaptiveScheduler(base_C, **sched_kw)
-                 if args.adaptive else None)
-        cluster = None
         vclock = 0.0
         missed = 0
         realized_sum = 0.0
         logged_t1, logged_t2 = [], []
         t0 = time.time()
-        for i in range(start, args.steps):
-            C = base_C if sched is None else sched.matrix()
-            row = (None if sched is None
-                   else jnp.asarray(sched.row_of_worker()))
-            toks, labs = lm_task_batches(part, C, i)
-            state, m, cluster = step_fn(
-                state, toks, labs,
-                jax.random.fold_in(seeds["delay_root"], i), cluster, row)
-            if sched is not None:
-                sched.observe(np.asarray(m["worker_t1"]))
-                if args.deadline_policy == "reissue":
-                    # undelivered tasks get re-gather priority next round
-                    sched.set_need(~np.asarray(m["delivered_tasks"]))
+        for i, state, m in straggler_rounds(
+                run, seeds["delay_root"], args.steps,
+                reissue=args.deadline_policy == "reissue"):
             if args.log_delays:
                 logged_t1.append(np.asarray(m["slot_t1"]))
                 logged_t2.append(np.asarray(m["slot_t2"]))

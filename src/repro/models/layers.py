@@ -22,14 +22,6 @@ from jax.sharding import PartitionSpec as P
 from ..sharding import BOTH, DATA, MODEL, current_mesh_ctx, shard
 from .config import ModelConfig
 
-# jax < 0.5 compat: shard_map lived under jax.experimental and pvary did not
-# exist (values were implicitly unreplicated there).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 Array = jax.Array
 PyTree = Any
 
@@ -299,7 +291,7 @@ def seq_sharded_decode_attention(cfg: ModelConfig, q: Array, kx: Array,
         out = (o_full / jnp.maximum(l, 1e-30)[..., None]).astype(q_l.dtype)
         return out.reshape(Bl, H, 1, dh), ck, cv
 
-    out, kf, vf = _shard_map(
+    out, kf, vf = jax.shard_map(
         block, mesh=ctx.mesh,
         in_specs=(P(dspec, None, None, None), P(dspec, None, None, None),
                   P(dspec, None, None, None), P(dspec, None, maxes, None),
@@ -706,15 +698,15 @@ def moe_apply(p: PyTree, cfg: ModelConfig, x: Array) -> Tuple[Array, Array]:
                                   e_start=e_start, n_local=n_local)
             out = lax.psum(out, maxes)
             # aux: sum disjoint local f_e*P_e terms over experts (model
-            # axis), mean over data shards; pvary the axes the tracker
-            # sees as invarying, then psum over everything so the scalar
-            # is replicated (out_specs P()).
-            aux = _pvary(aux, (maxes,) if tokens_sharded
-                                else all_axes)
+            # axis), mean over data shards; cast the axes the tracker
+            # sees as invarying to varying, then psum over everything so
+            # the scalar is replicated (out_specs P()).
+            aux = lax.pcast(aux, (maxes,) if tokens_sharded else all_axes,
+                            to="varying")
             aux = lax.psum(aux, all_axes) / ctx.data_size
             return out, aux
 
-        out, aux = _shard_map(
+        out, aux = jax.shard_map(
             block, mesh=ctx.mesh,
             in_specs=(P(dspec, None), P(None, None), P(maxes, None, None),
                       P(maxes, None, None), P(maxes, None, None)),

@@ -12,11 +12,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import inspect
 import threading
 from typing import Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -159,50 +159,22 @@ def shard_trials(fn, devices: Sequence[jax.Device], replicated: Tuple[int, ...] 
     ``replicated`` names positional argnums that every device sees whole
     (broadcast, not split): small runtime parameters like PRNG base keys,
     per-chunk offset vectors, and the bucketed evaluators' gather plans.
-    Replicated arguments skip the leading-axis reshape and ride into the
-    vmap with ``in_axes=None`` under a fully-replicated ``P()`` sharding.
 
-    Mechanism: the leading axis is reshaped to ``(d, per_device, ...)``,
-    ``fn`` is ``vmap``-ed over the device axis, and the whole thing is
-    jitted with ``NamedSharding(mesh, P(TRIAL_AXIS))`` on inputs and
-    outputs, so the GSPMD partitioner splits every per-iteration tensor of
-    the chunk scan across devices while the scan itself stays sequential
-    per shard.  This deliberately does NOT use ``shard_map``: on forced
-    multi-device host meshes (jax 0.4.x CPU) ``shard_map``-wrapped scan
-    programs miscompile — constant-initialized loop carries are aliased
-    across co-resident shards and fusion-dependent partial sums come out
-    wrong on every device but the first — while the identical program
-    partitioned via ``jit``/``NamedSharding`` (and via ``pmap``) is
-    bit-exact vs. the eager single-device result.
+    Mechanism: ``jax.shard_map`` runs ``fn`` itself on each device's block
+    of chunks, so every device compiles the single-device program at a
+    smaller chunk count.  Pallas kernels inside ``fn`` (the greedy
+    assignment) need this: XLA cannot partition a Mosaic call, so a
+    GSPMD-partitioned ``jit`` of the same scan is refused on a TPU.
 
-    The returned callable is fully jitted — callers must NOT wrap it in
-    another ``jax.jit`` (the reshapes below are free layout changes and the
-    inner jit caches per input shape)."""
-    devs = tuple(devices)
-    d = len(devs)
-    mesh = trial_mesh(devs)
-    sh = NamedSharding(mesh, P(TRIAL_AXIS))
-    rep = NamedSharding(mesh, P())
+    Returns ``jax.jit`` of the ``shard_map``-ped ``fn`` (callers must NOT
+    wrap it in another ``jax.jit``); ``fn``'s positional parameters are
+    counted from its signature."""
     repl = frozenset(replicated)
-    # the vmapped/jitted callable is built lazily on first use: in_axes /
-    # in_shardings are per-argument, and the argument count is only known
-    # at call time (jit caches per pytree structure after that).
-    cache: dict = {}
-
-    def sharded(*args):
-        nargs = len(args)
-        vfn = cache.get(nargs)
-        if vfn is None:
-            axes = tuple(None if i in repl else 0 for i in range(nargs))
-            shard_in = tuple(rep if i in repl else sh for i in range(nargs))
-            vfn = jax.jit(jax.vmap(fn, in_axes=axes),
-                          in_shardings=shard_in, out_shardings=sh)
-            cache[nargs] = vfn
-        parts = [jax.device_put(a, rep) if i in repl else jax.device_put(
-            jnp.reshape(a, (d, a.shape[0] // d) + a.shape[1:]), sh)
-            for i, a in enumerate(args)]
-        out = vfn(*parts)
-        return jax.tree_util.tree_map(
-            lambda x: jnp.reshape(x, (-1,) + x.shape[2:]), out)
-
-    return sharded
+    nargs = len(inspect.signature(fn).parameters)
+    specs = tuple(P() if i in repl else P(TRIAL_AXIS) for i in range(nargs))
+    # fn holds no collectives, so there is nothing for the varying-axes
+    # check to protect; it would only ask every constant scan carry in
+    # the engine to be marked varying
+    return jax.jit(jax.shard_map(fn, mesh=trial_mesh(tuple(devices)),
+                                 in_specs=specs, out_specs=P(TRIAL_AXIS),
+                                 check_vma=False))

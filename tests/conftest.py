@@ -1,20 +1,8 @@
-"""Test-session bootstrap.
-
-1. If ``hypothesis`` is not installed, register the deterministic fallback
-   shim (tests/_hypothesis_fallback.py) before any test module imports it,
-   so the suite still collects and runs.
-2. Lock the single-device CPU backend before any test imports
-   repro.launch.dryrun (whose module-level XLA_FLAGS would otherwise
-   inflate the device count for the whole pytest process — the 512-device
-   setting is for the dry-run subprocesses only).
+"""Test-session bootstrap: lock the single-device CPU backend before any
+test imports repro.launch.dryrun (whose module-level XLA_FLAGS would
+otherwise inflate the device count for the whole pytest process — the
+512-device setting is for the dry-run subprocesses only).
 """
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_fallback
-
-    _hypothesis_fallback.install()
-
 import jax
 
 jax.devices()

@@ -158,19 +158,37 @@ class TestGreedyAssign:
     """Pallas greedy row-assignment kernel vs the pure-jnp oracle.  The
     pick loop is integer-valued, so every comparison is bitwise."""
 
-    @pytest.mark.parametrize("n,r,B,bt", [
-        (8, 3, 64, 128),     # single partial block
-        (8, 3, 128, 128),    # exactly one block
-        (8, 3, 300, 128),    # multi-block with a ragged edge
-        (4, 1, 17, 8),       # tiny blocks, many grid steps
-        (12, 12, 50, 32),    # full load r = n
+    @pytest.mark.parametrize("n,r,B,bt,seed", [
+        (8, 3, 64, 128, 512),      # single partial block
+        (8, 3, 128, 128, 1024),    # exactly one block
+        (8, 3, 300, 128, 2400),    # multi-block with a ragged edge
+        (4, 1, 17, 8, 68),         # tiny blocks, many grid steps
+        (12, 12, 50, 32, 600),     # full load r = n
+        (5, 4, 8, 32, 1),          # cyclic rows tied in exact arithmetic
     ])
-    def test_matches_oracle(self, n, r, B, bt):
+    def test_matches_oracle(self, n, r, B, bt, seed):
         C = cyclic_to_matrix(n, r)
-        W, order, epick, need_row = _greedy_inputs(C, B, seed=n * B)
+        W, order, epick, need_row = _greedy_inputs(C, B, seed=seed)
         out = greedy_assign(W, order, epick, need_row, block_trials=bt)
         want = ref.greedy_assign_ref(W, order, epick, need_row)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+    @pytest.mark.parametrize("n,r,B", [(5, 4, 8), (16, 4, 64), (64, 8, 32)])
+    def test_coverage_scores_match_float64(self, n, r, B):
+        # kernel and oracle share coverage_scores; check the sum itself
+        # against float64 cov @ W.T within float32 summation rounding
+        from repro.kernels.greedy_assign import coverage_scores
+        W, _, _, _ = _greedy_inputs(cyclic_to_matrix(n, r), B, seed=n)
+        cov = jax.random.uniform(jax.random.PRNGKey(B), (B, n),
+                                 minval=0.0, maxval=50.0)
+        got = np.asarray(coverage_scores(cov, [W.T[t:t + 1]
+                                               for t in range(n)]))
+        c64, w64 = np.asarray(cov, np.float64), np.asarray(W, np.float64)
+        want = c64 @ w64.T
+        bound = (n + 1) * np.finfo(np.float32).eps * (np.abs(c64)
+                                                     @ np.abs(w64).T)
+        assert got.shape == (B, n)
+        assert (np.abs(got - want) <= bound).all()
 
     def test_need_vector_reissue_priority(self):
         C = staircase_to_matrix(8, 3)

@@ -117,3 +117,31 @@ def test_shard_noop_without_mesh():
     from repro.sharding import DATA, MODEL
     y = shard(x, DATA, MODEL)
     assert y is x
+
+
+class TestCompileCache:
+    """``enable_compile_cache`` places JAX's persistent cache from outside:
+    ``JAX_COMPILATION_CACHE_DIR`` when set (the code sets no other
+    directory), else the fixed ``.jax_cache`` at the checkout root."""
+
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_defaults_to_checkout_root(self, monkeypatch):
+        from pathlib import Path
+        from repro import compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = compile_cache.enable_compile_cache()
+        root = Path(__file__).resolve().parents[1]
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+
+    def test_environment_variable_wins(self, monkeypatch, tmp_path):
+        from repro import compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before

@@ -122,6 +122,21 @@ def test_chunked_samples_equal_unchunked(chunk):
     assert (full == part).all()
 
 
+@pytest.mark.parametrize("chunk", [7, 250])
+def test_chunked_samples_equal_unchunked_vector_mean(chunk):
+    # per-worker means (ec2_like): the truncation bounds stay scalars, so
+    # no draw depends on how the trial axis is cut
+    n, r, k, trials = 6, 3, 4, 1000
+    m = ec2_like(n)
+    C = cyclic_to_matrix(n, r)
+    full = np.asarray(completion_samples(to_spec("x", C), m, n,
+                                         trials=trials, seed=0, k=k))
+    part = np.asarray(completion_samples(to_spec("x", C), m, n,
+                                         trials=trials, seed=0, k=k,
+                                         chunk=chunk))
+    np.testing.assert_array_equal(full, part)
+
+
 def test_chunked_sweep_means_equal_unchunked():
     n, r, trials = 6, 6, 2000
     m = scenario1()
@@ -302,10 +317,8 @@ def test_sweep_rounds_validation():
 
 def test_rounds_trajectories_chunk_invariant_and_consistent():
     n, r, k, trials, rounds = 6, 3, 5, 400, 5
-    # scalar-mean base: per-trial draws are bit-identical under any
-    # chunking (vector-mean bases like ec2_like compile to slightly
-    # different fusions per chunk shape — 1-ulp, covered by allclose in
-    # test_ec2_cluster_chunking_close below).
+    # per-trial draws are bit-identical under any chunking (vector-mean
+    # bases too: test_ec2_cluster_chunking_close below).
     from repro.core import MarkovRegimeProcess, heterogeneous_scales
     proc = MarkovRegimeProcess(base=scenario1(),
                                worker_scale=heterogeneous_scales(n, 2.0),
@@ -329,8 +342,8 @@ def test_rounds_trajectories_chunk_invariant_and_consistent():
 
 
 def test_ec2_cluster_chunking_close():
-    """Vector-mean bases (ec2_like) are chunk-invariant to float32 ulp —
-    XLA fuses the truncnorm math differently per chunk shape."""
+    """Vector-mean bases (ec2_like) are chunk-invariant bit for bit, also
+    between one chunk per scan and several."""
     n, r, k = 6, 3, 5
     proc = ec2_cluster(n, spread=2.0, persistence=0.9)
     spec = to_spec("cs", cyclic_to_matrix(n, r))
@@ -338,7 +351,10 @@ def test_ec2_cluster_chunking_close():
                                          trials=300, seed=0))
     part = np.asarray(trajectory_samples(spec, proc, n, rounds=4, k=k,
                                          trials=300, seed=0, chunk=77))
-    np.testing.assert_allclose(part, full, rtol=1e-5)
+    np.testing.assert_array_equal(part, full)
+    one = np.asarray(trajectory_samples(spec, proc, n, rounds=4, k=k,
+                                        trials=77, seed=0, chunk=77))
+    np.testing.assert_array_equal(one, full[:77])
 
 
 def test_adaptive_beats_static_on_persistent_heterogeneous_cluster():

@@ -177,6 +177,20 @@ class TestShardedBitExact:
         if r1.degradation or r4.degradation:
             tree_equal(r1.degradation, r4.degradation)
 
+    def test_sweep_rounds_vector_mean_process(self):
+        # ec2_cluster: per-worker base means under the regime chain
+        from repro.core import ec2_cluster
+        specs = _specs() + [adaptive_spec("rebal", cyclic_to_matrix(N, 6),
+                                          rebalance=True, loads=[3] * N)]
+        kw = dict(rounds=4, k=6, trials=160, seed=0, chunk=40,
+                  censored_feedback=True)
+        proc = ec2_cluster(N, persistence=0.95)
+        r1 = sweep_rounds(specs, proc, N, devices=1, **kw)
+        r4 = sweep_rounds(specs, proc, N, devices=4, **kw)
+        tree_equal(r1.per_round, r4.per_round)
+        tree_equal(r1.stderr, r4.stderr)
+        tree_equal(r1.wallclock, r4.wallclock)
+
     def test_trajectory_samples(self):
         kw = dict(rounds=3, k=6, trials=61, seed=5, chunk=10, deadline=0.004)
         t1 = trajectory_samples(_specs()[3], _markov(), N, devices=1, **kw)
