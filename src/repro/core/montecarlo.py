@@ -131,6 +131,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..sharding import shard_trials, trial_devices
 from .spec import (DEADLINE_POLICIES, _internal, _legacy_warning,
                    validate_deadline)
@@ -1162,7 +1163,8 @@ def _get_exec(sig: tuple, model, devices: tuple):
             s1 = {g: _tree_sum(v) for g, v in s1.items()}
             return carry, (s0, s1)
 
-        _, parts = jax.lax.scan(body, None, starts)
+        with jax.named_scope("engine.sums_scan"):
+            _, parts = jax.lax.scan(body, None, starts)
         return parts               # 2 x {group: (nc, S, L)} partials
 
     def samples_scan(base_key, starts, offs, limit, params):
@@ -1172,7 +1174,8 @@ def _get_exec(sig: tuple, model, devices: tuple):
             tids = jnp.minimum(start + offs, limit - 1)
             return carry, stats_fn(_fold_keys(base_key, tids), params)
 
-        _, ys = jax.lax.scan(body, None, starts)
+        with jax.named_scope("engine.samples_scan"):
+            _, ys = jax.lax.scan(body, None, starts)
         return ys                  # {group: (nc, chunk, S, L)}
 
     if len(devices) > 1:
@@ -1335,6 +1338,7 @@ def _validate_single_round(specs: Sequence[SchemeSpec], n: int,
     return specs
 
 
+@obs.span("engine.dispatch")
 def _dispatch_run(specs: Sequence[SchemeSpec], model, n: int, *, trials: int,
                   seed: int, chunk: Optional[int], ks: Optional[int],
                   want_samples: bool, devices=None) -> _Pending:
@@ -1367,19 +1371,25 @@ def _dispatch_run(specs: Sequence[SchemeSpec], model, n: int, *, trials: int,
     p0, p1 = jsums(base_key, starts, offs, limit, pj)
 
     def resolve_sums():
-        # per-chunk float32 partials -> float64 in global chunk order: the
-        # same reduction whatever the device count (bit-exact sharding).
-        mu_g = {g: np.asarray(v, np.float64).sum(axis=0) / trials
-                for g, v in p0.items()}
-        sq_g = {g: np.asarray(v, np.float64).sum(axis=0)
-                for g, v in p1.items()}
-        means, stderr = {}, {}
-        for name, (g, i) in slots.items():
-            mu = mu_g[g][i]
-            var = np.maximum(sq_g[g][i] / trials - mu * mu, 0.0)
-            means[name] = mu
-            stderr[name] = np.sqrt(var / trials)
-        return means, stderr
+        with obs.span("engine.wait"):
+            jax.block_until_ready((p0, p1))
+        with obs.span("engine.combine"):
+            obs.count("engine.fetched_bytes",
+                      sum(v.nbytes for v in (*p0.values(), *p1.values())))
+            # per-chunk float32 partials -> float64 in global chunk order:
+            # the same reduction whatever the device count (bit-exact
+            # sharding).
+            mu_g = {g: np.asarray(v, np.float64).sum(axis=0) / trials
+                    for g, v in p0.items()}
+            sq_g = {g: np.asarray(v, np.float64).sum(axis=0)
+                    for g, v in p1.items()}
+            means, stderr = {}, {}
+            for name, (g, i) in slots.items():
+                mu = mu_g[g][i]
+                var = np.maximum(sq_g[g][i] / trials - mu * mu, 0.0)
+                means[name] = mu
+                stderr[name] = np.sqrt(var / trials)
+            return means, stderr
 
     return _Pending(resolve_sums)
 
@@ -1440,6 +1450,7 @@ def _reject_single_round_trace(record_trace: bool, fn: str) -> None:
                          f"per-round delay tables to record")
 
 
+@obs.span("engine.sweep")
 def sweep(specs: Sequence[SchemeSpec], model, n: int, *, trials: int = 20000,
           seed: int = 0, chunk: Optional[int] = None,
           ks: Optional[int] = None, record_trace: bool = False,
@@ -1561,6 +1572,7 @@ class ResumableSweep:
     def spec_names(self) -> Tuple[str, ...]:
         return tuple(sp.name for sp in self._specs)
 
+    @obs.span("engine.extend")
     def extend_trials(self, total: int) -> SweepResult:
         """Continue the sweep to ``total`` trials and return the combined
         result — bit-exact with ``sweep(..., trials=total)`` at the same
@@ -1577,33 +1589,43 @@ class ResumableSweep:
                 f"clamps its trailing trial ids, so the sweep cannot be "
                 f"extended past it (keep every total but the last "
                 f"chunk-aligned)")
-        add = total - self._done
-        nc = -(-add // self._chunk)
-        devs = trial_devices(self._devices)
-        d_eff = min(len(devs), nc)
-        nc_pad = -(-nc // d_eff) * d_eff
-        sig, params, slots = _eval_layout(self._specs, self._n, self._r_max,
-                                          self._ks)
-        jsums, jsamples = _get_exec(sig, self._model, devs[:d_eff])
-        first = self._done // self._chunk
-        starts = ((jnp.arange(nc_pad, dtype=jnp.int32) + jnp.int32(first))
-                  * jnp.int32(self._chunk))
-        offs = jnp.arange(self._chunk, dtype=jnp.int32)
-        limit = jnp.int32(total)
-        pj = {k2: jnp.asarray(v) for k2, v in params.items()}
-        p0, p1 = jsums(self._base_key, starts, offs, limit, pj)
-        ys = (jsamples(self._base_key, starts, offs, limit, pj)
-              if self._keep else None)
-        for name, (g, i) in slots.items():
-            self._p0[name].append(np.asarray(p0[g], np.float32)[:, i, :])
-            self._p1[name].append(np.asarray(p1[g], np.float32)[:, i, :])
-            if ys is not None:
-                v = ys[g]                      # (nc_pad, chunk, S, L)
-                flat = v[:, :, i, :].reshape(nc_pad * self._chunk,
-                                             v.shape[-1])
-                self._samp[name].append(np.asarray(flat[:add], np.float32))
-        self._done = total
-        return self.result()
+        with obs.span("engine.dispatch"):
+            add = total - self._done
+            nc = -(-add // self._chunk)
+            devs = trial_devices(self._devices)
+            d_eff = min(len(devs), nc)
+            nc_pad = -(-nc // d_eff) * d_eff
+            sig, params, slots = _eval_layout(self._specs, self._n,
+                                              self._r_max, self._ks)
+            jsums, jsamples = _get_exec(sig, self._model, devs[:d_eff])
+            first = self._done // self._chunk
+            starts = ((jnp.arange(nc_pad, dtype=jnp.int32)
+                       + jnp.int32(first)) * jnp.int32(self._chunk))
+            offs = jnp.arange(self._chunk, dtype=jnp.int32)
+            limit = jnp.int32(total)
+            pj = {k2: jnp.asarray(v) for k2, v in params.items()}
+            p0, p1 = jsums(self._base_key, starts, offs, limit, pj)
+            ys = (jsamples(self._base_key, starts, offs, limit, pj)
+                  if self._keep else None)
+        with obs.span("engine.wait"):
+            jax.block_until_ready((p0, p1, ys))
+        with obs.span("engine.combine"):
+            h0 = {g: np.asarray(v, np.float32) for g, v in p0.items()}
+            h1 = {g: np.asarray(v, np.float32) for g, v in p1.items()}
+            fetched = sum(h.nbytes for h in (*h0.values(), *h1.values()))
+            for name, (g, i) in slots.items():
+                self._p0[name].append(h0[g][:, i, :])
+                self._p1[name].append(h1[g][:, i, :])
+                if ys is not None:
+                    v = ys[g]                  # (nc_pad, chunk, S, L)
+                    flat = v[:, :, i, :].reshape(nc_pad * self._chunk,
+                                                 v.shape[-1])
+                    x = np.asarray(flat[:add], np.float32)
+                    fetched += x.nbytes
+                    self._samp[name].append(x)
+            obs.count("engine.fetched_bytes", fetched)
+            self._done = total
+            return self.result()
 
     def result(self) -> SweepResult:
         """Combined result over all trials evaluated so far (same float64
@@ -2009,9 +2031,10 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
             init = (pstate, jnp.ones((chunk, n), jnp.float32),
                     needs0, backs0)
 
-        _, ys = jax.lax.scan(body, init,
-                             (jnp.swapaxes(allk[:, 1:], 0, 1),
-                              jnp.arange(rounds, dtype=jnp.int32)))
+        with jax.named_scope("engine.rounds_scan"):
+            _, ys = jax.lax.scan(body, init,
+                                 (jnp.swapaxes(allk[:, 1:], 0, 1),
+                                  jnp.arange(rounds, dtype=jnp.int32)))
         return ys             # ({name: (rounds, chunk)}, {name: aux dicts})
 
     return rounds_fn

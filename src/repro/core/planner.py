@@ -52,6 +52,7 @@ import numpy as np
 
 from . import montecarlo as mc
 from . import theory
+from .. import obs
 from .grid import GridSpec, _cell_name, _family_spec
 from .spec import RoundConfig, _internal
 
@@ -280,6 +281,7 @@ def _metric_column(samp: np.ndarray, p: _Point, n: int) -> np.ndarray:
     return x[:, p.k - 1]
 
 
+@obs.span("plan.decide")
 def plan(grid: GridSpec, model, *, k: Optional[int] = None,
          base_trials: Optional[int] = None, eta: int = 4, z: float = 3.0,
          theory_prune: bool = True, prune_slack: float = 0.25,
@@ -344,8 +346,9 @@ def plan(grid: GridSpec, model, *, k: Optional[int] = None,
     predicted: Dict[str, float] = {}
     pdfs = theory.delay_model_pdfs(model) if theory_prune else None
     if pdfs is not None:
-        pruned, points, predicted = _theory_prune(points, pdfs, n,
-                                                  prune_slack)
+        with obs.span("plan.prune"):
+            pruned, points, predicted = _theory_prune(points, pdfs, n,
+                                                      prune_slack)
         for cname, rec in pruned.items():
             records[cname] = {"status": "pruned", "trials": 0, **rec}
 
@@ -373,81 +376,88 @@ def plan(grid: GridSpec, model, *, k: Optional[int] = None,
     spec_trials: Dict[str, int] = {}
 
     for rung, t in enumerate(ladder):
-        rs.extend_trials(t)
-        samp = rs.samples()
-        cols = {p.name: _metric_column(samp[p.spec_name], p, n)
-                for p in alive}
-        means = {nm: float(x.mean()) for nm, x in cols.items()}
-        inc = min(alive, key=lambda p: means[p.name])   # incumbent argmin
-        x_inc = cols[inc.name]
-        eliminated: list[dict] = []
-        survivors: list[_Point] = []
-        for p in alive:
-            if p is inc:
-                survivors.append(p)
-                continue
-            d = cols[p.name] - x_inc                    # paired gap, CRN
-            gap = float(d.mean())
-            gap_se = float(d.std(ddof=1) / math.sqrt(t)) if t > 1 else 0.0
-            if rung < len(ladder) - 1 and gap - z * gap_se > 0.0:
-                x = cols[p.name]
-                records[p.name] = {
-                    "status": "eliminated", "trials": t, "rung": rung,
-                    "mean": means[p.name],
-                    "stderr": float(x.std(ddof=1) / math.sqrt(t)),
-                    "gap": gap, "gap_stderr": gap_se,
-                    "vs": inc.name,
-                }
-                eliminated.append({"point": p.name, "gap": gap,
-                                   "gap_stderr": gap_se})
-            else:
-                survivors.append(p)
-        trajectory.append({
-            "rung": rung, "trials": t, "incumbent": inc.name,
-            "survivors": [p.name for p in survivors],
-            "eliminated": [e["point"] for e in eliminated],
-        })
-        dropped_specs = ({p.spec_name for p in alive}
-                         - {p.spec_name for p in survivors})
-        for snm in dropped_specs:
-            spec_trials[snm] = t
-        alive = survivors
-        if rung < len(ladder) - 1 and dropped_specs:
-            rs.narrow([p.spec_name for p in alive])
+        with obs.span("plan.rung", rung=rung, trials=t):
+            rs.extend_trials(t)
+            with obs.span("plan.race"):
+                samp = rs.samples()
+                cols = {p.name: _metric_column(samp[p.spec_name], p, n)
+                        for p in alive}
+                means = {nm: float(x.mean()) for nm, x in cols.items()}
+                # the incumbent: this rung's argmin
+                inc = min(alive, key=lambda p: means[p.name])
+                x_inc = cols[inc.name]
+                eliminated: list[dict] = []
+                survivors: list[_Point] = []
+                for p in alive:
+                    if p is inc:
+                        survivors.append(p)
+                        continue
+                    d = cols[p.name] - x_inc        # paired gap, CRN
+                    gap = float(d.mean())
+                    gap_se = (float(d.std(ddof=1) / math.sqrt(t)) if t > 1
+                              else 0.0)
+                    if rung < len(ladder) - 1 and gap - z * gap_se > 0.0:
+                        x = cols[p.name]
+                        records[p.name] = {
+                            "status": "eliminated", "trials": t,
+                            "rung": rung,
+                            "mean": means[p.name],
+                            "stderr": float(x.std(ddof=1) / math.sqrt(t)),
+                            "gap": gap, "gap_stderr": gap_se,
+                            "vs": inc.name,
+                        }
+                        eliminated.append({"point": p.name, "gap": gap,
+                                           "gap_stderr": gap_se})
+                    else:
+                        survivors.append(p)
+                trajectory.append({
+                    "rung": rung, "trials": t, "incumbent": inc.name,
+                    "survivors": [p.name for p in survivors],
+                    "eliminated": [e["point"] for e in eliminated],
+                })
+                dropped_specs = ({p.spec_name for p in alive}
+                                 - {p.spec_name for p in survivors})
+                for snm in dropped_specs:
+                    spec_trials[snm] = t
+                alive = survivors
+                if rung < len(ladder) - 1 and dropped_specs:
+                    rs.narrow([p.spec_name for p in alive])
     for snm in {p.spec_name for p in alive}:
         spec_trials[snm] = grid.trials
 
     # ---- final selection + survivor records -----------------------------
-    samp = rs.samples()
-    final_cols = {p.name: _metric_column(samp[p.spec_name], p, n)
-                  for p in alive}
-    winner = min(alive, key=lambda p: float(final_cols[p.name].mean()))
-    w_x = final_cols[winner.name]
-    w_mean = float(w_x.mean())
-    w_se = float(w_x.std(ddof=1) / math.sqrt(grid.trials))
-    for p in alive:
-        x = final_cols[p.name]
-        rec = {"status": "won" if p is winner else "survived",
-               "trials": grid.trials, "mean": float(x.mean()),
-               "stderr": float(x.std(ddof=1) / math.sqrt(grid.trials))}
-        if p is not winner:
-            d = x - w_x
-            rec["gap"] = float(d.mean())
-            rec["gap_stderr"] = float(d.std(ddof=1)
-                                      / math.sqrt(grid.trials))
-            rec["vs"] = winner.name
-        records[p.name] = rec
-    for nm, mu in predicted.items():
-        if nm in records:
-            records[nm]["theory_mean"] = mu
+    with obs.span("plan.select"):
+        samp = rs.samples()
+        final_cols = {p.name: _metric_column(samp[p.spec_name], p, n)
+                      for p in alive}
+        winner = min(alive, key=lambda p: float(final_cols[p.name].mean()))
+        w_x = final_cols[winner.name]
+        w_mean = float(w_x.mean())
+        w_se = float(w_x.std(ddof=1) / math.sqrt(grid.trials))
+        for p in alive:
+            x = final_cols[p.name]
+            rec = {"status": "won" if p is winner else "survived",
+                   "trials": grid.trials, "mean": float(x.mean()),
+                   "stderr": float(x.std(ddof=1) / math.sqrt(grid.trials))}
+            if p is not winner:
+                d = x - w_x
+                rec["gap"] = float(d.mean())
+                rec["gap_stderr"] = float(d.std(ddof=1)
+                                          / math.sqrt(grid.trials))
+                rec["vs"] = winner.name
+            records[p.name] = rec
+        for nm, mu in predicted.items():
+            if nm in records:
+                records[nm]["theory_mean"] = mu
 
     # ---- predicted-vs-LB gap at the winning operating point -------------
     trials_spent = sum(spec_trials.values())
     lb_sp = mc.lb_spec(winner.r, messages=winner.messages,
                        comm_eps=winner.comm_eps)
-    lb_res = mc.sweep([lb_sp], model, n, trials=grid.trials,
-                      seed=grid.seed, chunk=chunk, ks=None,
-                      devices=devices)
+    with obs.span("plan.lb_sweep"):
+        lb_res = mc.sweep([lb_sp], model, n, trials=grid.trials,
+                          seed=grid.seed, chunk=chunk, ks=None,
+                          devices=devices)
     # coded winners recover the full gradient at their decode threshold,
     # so the comparable oracle target is k = n (their own threshold can
     # exceed n and is not an order-statistic index of the lb spec).

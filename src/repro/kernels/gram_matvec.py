@@ -96,6 +96,7 @@ def gram_matvec_pallas(X: jax.Array, theta: jax.Array, *,
         out_specs=pl.BlockSpec((bb, 1), lambda i, j: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, 1), jnp.float32),
         interpret=interpret,
+        name="gram_xt_theta",
     )(Xp, th2)
 
     y = pl.pallas_call(
@@ -106,6 +107,7 @@ def gram_matvec_pallas(X: jax.Array, theta: jax.Array, *,
         out_specs=pl.BlockSpec((bd, 1), lambda j, i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((dp, 1), jnp.float32),
         interpret=interpret,
+        name="gram_x_u",
     )(Xp, u)
 
     return y[:d, 0].astype(X.dtype)

@@ -136,6 +136,7 @@ def greedy_assign_pallas(W: jax.Array, order: jax.Array, epick: jax.Array,
         out_specs=pl.BlockSpec((bt, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, n), jnp.int32),
         interpret=interpret,
+        name="greedy_assign",
     )(W, W.T, order.astype(jnp.int32), 1.0 / epick,
       need_row.astype(jnp.float32))
 

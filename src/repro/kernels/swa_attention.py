@@ -115,5 +115,6 @@ def swa_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="swa_attention",
     )(qh, kh, vh)
     return out.transpose(1, 0, 2)[:T]

@@ -16,18 +16,29 @@ when the winner is a TO-matrix family (cs/ss/ra) — feed it straight to
 is the final-rung count, so the reported argmin carries the same
 confidence as the exhaustive grid at that budget; the planner typically
 spends >= 5x fewer trial-evaluations getting there.
+
+``--profile DIR`` traces the decision with the JAX profiler into ``DIR``
+(open it in TensorBoard or Perfetto) and prints, for each of the
+program's spans (``repro.obs``: ``plan.prune``, ``plan.race``,
+``engine.extend``, ...), how often it ran and its total and self
+seconds, then the program's counters.  A first decision in a process
+includes its compiles.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
+
+import jax
 
 from ..core.grid import FAMILIES, GridSpec
 from ..core.planner import plan
 from .grid import MODELS, _axis, _build_model
 from ..compile_cache import enable_compile_cache
+from .. import obs
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -68,7 +79,23 @@ def build_parser() -> argparse.ArgumentParser:
                          "(TO-matrix winners only)")
     ap.add_argument("--out", default="out/plan_result.json",
                     help="artifact path (directories are created)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="trace the decision into DIR with the JAX "
+                         "profiler and print each program span's count, "
+                         "total and self seconds")
     return ap
+
+
+def print_spans() -> None:
+    """One line per recorded span name (count, total and self seconds,
+    largest total first), then the counters."""
+    rows = sorted(obs.summary().items(), key=lambda kv: -kv[1][1])
+    print(f"{'span':<16} {'count':>6} {'total s':>10} {'self s':>10}")
+    for name, (cnt, total, own) in rows:
+        print(f"{name:<16} {cnt:>6} {total * 1e-9:>10.4f} "
+              f"{own * 1e-9:>10.4f}")
+    for name, v in sorted(obs.counters().items()):
+        print(f"counter {name} {v}")
 
 
 def main(argv=None) -> int:
@@ -88,9 +115,13 @@ def main(argv=None) -> int:
           f"(final rung {gs.trials:,} trials/point, model={args.model})",
           flush=True)
 
-    res = plan(gs, model, k=args.k, base_trials=args.base_trials,
-               eta=args.eta, z=args.z,
-               theory_prune=not args.no_theory_prune, devices=args.devices)
+    obs.reset()
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        res = plan(gs, model, k=args.k, base_trials=args.base_trials,
+                   eta=args.eta, z=args.z,
+                   theory_prune=not args.no_theory_prune,
+                   devices=args.devices)
     res.meta["model"] = args.model
     res.meta["spec"] = gs.to_json()
 
@@ -123,6 +154,9 @@ def main(argv=None) -> int:
         if args.emit_config:
             print(f"(--emit-config {args.emit_config} skipped)")
     print(f"artifact: {args.out}")
+    if args.profile:
+        print(f"trace: {args.profile}")
+        print_spans()
     return 0
 
 

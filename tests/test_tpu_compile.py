@@ -69,6 +69,7 @@ def test_greedy_assign_compiles(one_chip, n, B):
                         _spec((B, n), jnp.float32, one_chip),
                         _spec((B, n), jnp.float32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "%greedy_assign" in compiled.as_text()     # the kernel's name
 
 
 @pytest.mark.parametrize("d,b", [(400, 60), (4096, 4096)])
@@ -77,6 +78,8 @@ def test_gram_matvec_compiles(one_chip, d, b):
     compiled = fn.lower(_spec((d, b), jnp.float32, one_chip),
                         _spec((d,), jnp.float32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "%gram_xt_theta" in compiled.as_text()     # the kernels' names
+    assert "%gram_x_u" in compiled.as_text()
 
 
 def test_greedy_assign_compiles_trial_sharded(topo, four_chips):
@@ -92,3 +95,4 @@ def test_greedy_assign_compiles_trial_sharded(topo, four_chips):
                         _spec((B, n), jnp.float32, split)).compile()
     assert len(topo.devices) == 4
     assert "tpu_custom_call" in compiled.as_text()
+    assert "%greedy_assign" in compiled.as_text()
