@@ -175,7 +175,7 @@ def pcmm_decode(results: np.ndarray, betas_rx: np.ndarray, n: int
 # --------------------- completion-time simulation ----------------------------
 # Backed by the fused sweep engine (montecarlo.py): per-trial subkeys mean
 # the draws are the common random numbers shared with the uncoded schemes
-# when evaluated inside one sweep, and lax.top_k replaces the full sort.
+# when evaluated inside one sweep, and a rank count replaces the full sort.
 
 def simulate_pc_completion(model, n: int, r: int, *, trials: int = 10000,
                            seed: int = 0, chunk: int | None = None

@@ -241,7 +241,7 @@ def _winner_weights(C: Array, s: Array, tau: Array, k: int,
 # ---------------- Monte-Carlo drivers ----------------------------------------
 # Thin wrappers over the fused sweep engine (see montecarlo.py): one
 # per-trial PRNG subkey stream, static gather layout for eq. (2), chunkable
-# trial streaming, and lax.top_k for single-k order statistics.
+# trial streaming, and a rank count for single-k order statistics.
 
 def simulate_completion(C: np.ndarray, model, k: int, *, trials: int = 10000,
                         seed: int = 0, chunk: int | None = None) -> Array:

@@ -16,7 +16,7 @@ replaces all of that with ONE jitted evaluator that:
    is O(chunk * n * r) and 10^6+ trials run on a laptop;
 4. returns completion times for EVERY k in 1..n from one sort of the task
    arrivals (a whole Fig.-7 k-sweep is one call), while single-k queries
-   take the cheaper ``lax.top_k`` partial-selection path;
+   take one order statistic by a sort-free rank count (``_kth_smallest``);
 5. computes task arrival times with a statically precomputed gather +
    min-reduction (each task's copy positions are known from the TO matrix
    at trace time) instead of a dynamic scatter-min — the TPU-friendly form.
@@ -580,6 +580,41 @@ def _smallest(x: Array, k: int) -> Array:
     return -jax.lax.top_k(-x, k)[0]
 
 
+#: widest last axis whose k-th order statistic is taken by rank counting.
+#: On a TPU v5e, ``top_k`` and ``jnp.sort`` lower to a (value, index) sort
+#: whatever k is, and the rank count's O(L^2) compares beat it only on
+#: narrow axes.  One v5e, the 16th smallest of f32[262144, 5, L], ms per
+#: call, rank count against top_k: 1.37 / 1.82 at L = 16, 3.26 / 4.01 at
+#: 32, 9.30 / 8.57 at 64, 87.3 / 26.7 at 128.
+_RANK_COUNT_MAX_WIDTH = 32
+
+
+def _by_rank_count(width: int) -> bool:
+    return width <= _RANK_COUNT_MAX_WIDTH
+
+
+def _kth_smallest(x: Array, k) -> Array:
+    """The exact k-th smallest entry of x along the last axis, shape
+    ``(..., 1)``; ``k`` (1-based) is a Python int or an int array that
+    broadcasts against ``x[..., :1]``.
+
+    On a narrow axis this is a fused rank count, not a sort: entry i's rank
+    is ``#{j: x_j < x_i} + #{j < i: x_j == x_i}``, so exactly one entry has
+    each rank (ties and ``+inf`` sentinels included) and the result equals
+    ``sort(x)[..., k-1]`` bit for bit."""
+    L = x.shape[-1]
+    if not _by_rank_count(L):
+        if isinstance(k, int):
+            return _smallest(x, k)[..., -1:]
+        srt = jnp.sort(x, axis=-1)
+        return jnp.take_along_axis(
+            srt, jnp.broadcast_to(k - 1, srt.shape[:-1] + (1,)), axis=-1)
+    xi, xj = x[..., :, None], x[..., None, :]
+    before = np.tril(np.ones((L, L), bool), -1)             # [i, j]: j < i
+    rank = ((xj < xi) | ((xj == xi) & before)).sum(-1, dtype=jnp.int32)
+    return jnp.max(jnp.where(rank == k - 1, x, -INF), axis=-1, keepdims=True)
+
+
 def _stat_width(spec: SchemeSpec, n: int, ks: Optional[int]) -> int:
     if spec.kind in ("pc", "pcmm"):        # fixed decode thresholds
         return 1
@@ -673,7 +708,7 @@ def _build_eval(specs: Tuple[SchemeSpec, ...], n: int, r_max: int,
             if ks is None:
                 stat = jnp.sort(tau, axis=-1)                # all k at once
             else:
-                stat = _smallest(tau, ks)[..., -1:]          # k-th only
+                stat = _kth_smallest(tau, ks)                # k-th only
             if DL is not None:
                 by_s = (tau <= DL).sum(-1).astype(jnp.float32)
                 dv_s = jnp.isfinite(tau).sum(-1).astype(jnp.float32)
@@ -711,7 +746,7 @@ def _build_eval(specs: Tuple[SchemeSpec, ...], n: int, r_max: int,
                 if sp.comm_eps:
                     tw = tw + jnp.float32(sp.comm_eps)   # its single message
                 th = _pc_threshold(n, r)   # PC's own decode threshold — the
-                out[sp.name] = _smallest(tw, th)[..., -1:]   # sweep k never
+                out[sp.name] = _kth_smallest(tw, th)         # sweep k never
                 # applies to coded schemes (same rule as pcmm below)
             elif sp.kind == "pcmm":
                 th = _pcmm_threshold(n)
@@ -743,7 +778,7 @@ def _build_eval(specs: Tuple[SchemeSpec, ...], n: int, r_max: int,
 # same shape bucket shares one executable.  Padding is value-exact: padded
 # plan entries read the +inf sentinel (transparent to min / top_k), padded
 # offsets are 0.0 (``x + 0.0`` is bitwise ``x`` for delays), and the pc
-# order statistic is taken from a full sort at a runtime index — so the
+# order statistic is taken by rank at a runtime threshold — so the
 # bucketed path is bit-exact with the per-spec path under CRN.
 # (``_build_eval`` stays as-is for the rounds axis, whose adaptive scan
 # re-evaluates baked static specs every round.)
@@ -869,33 +904,39 @@ def _build_bucket_eval(sig):
             tau = task_arrival_times_gather(
                 params["to_plan"], s, params["to_off"])
             out["to"] = (jnp.sort(tau, axis=-1) if ks is None
-                         else _smallest(tau, ks)[..., -1:])
+                         else _kth_smallest(tau, ks))
         if S_tau:
             out["tau"] = task_arrival_times_gather(
                 params["tau_plan"], s, params["tau_off"])
         if F_lb:
             win = s_pad[:, params["lb_idx"]] + params["lb_off"]
-            w = n if ks is None else ks
-            fs = _smallest(win, w)
-            out["lb"] = fs if ks is None else fs[..., -1:]
+            out["lb"] = (_smallest(win, n) if ks is None
+                         else _kth_smallest(win, ks))
         if F_pcmm:
-            th = _pcmm_threshold(n)
             win = s_pad[:, params["pcmm_idx"]] + params["pcmm_off"]
-            out["pcmm"] = _smallest(win, th)[..., -1:]
+            out["pcmm"] = _kth_smallest(win, _pcmm_threshold(n))
         if P_pc:
             # per-worker one-shot times at each pc spec's own closing slot,
-            # ranked by a full sort so the decode threshold (which varies
-            # with the runtime load) can be a runtime gather index — the
-            # th-th order statistic is the same value either way.
+            # ranked at the spec's decode threshold, a runtime value since
+            # it varies with the load.
             tw = jnp.moveaxis(s[..., params["pc_slot"]], -1, -2)
             tw = tw + params["pc_eps"][:, None]            # (chunk, P, n)
-            srt = jnp.sort(tw, axis=-1)
-            idx = jnp.broadcast_to(params["pc_th"][:, None],
-                                   (srt.shape[0], P_pc, 1))
-            out["pc"] = jnp.take_along_axis(srt, idx, axis=-1)
+            out["pc"] = _kth_smallest(tw, params["pc_th"][:, None] + 1)
         return out
 
     return eval_fn
+
+
+def _rank_count_columns(sig) -> int:
+    """Scheme columns per trial whose statistic ``_build_bucket_eval``
+    takes by rank count (``engine.select_rows`` counts them per row)."""
+    _, n, r_max, ks, S_to, _, _, _, F_lb, F_pcmm, P_pc = sig
+    cols = P_pc if _by_rank_count(n) else 0
+    if ks is not None and _by_rank_count(n):
+        cols += S_to
+    if _by_rank_count(n * r_max):
+        cols += F_pcmm + (F_lb if ks is not None else 0)
+    return cols
 
 
 def _build_stats_fn(sig, model):
@@ -1354,6 +1395,7 @@ def _dispatch_run(specs: Sequence[SchemeSpec], model, n: int, *, trials: int,
     base_key = jax.random.PRNGKey(seed)
     starts, offs, limit = _scan_coords(trials, chunk, nc_pad)
     pj = {k2: jnp.asarray(v) for k2, v in params.items()}
+    obs.count("engine.select_rows", padded * _rank_count_columns(sig))
 
     if want_samples:
         ys = jsamples(base_key, starts, offs, limit, pj)
@@ -1469,7 +1511,7 @@ def sweep(specs: Sequence[SchemeSpec], model, n: int, *, trials: int = 20000,
             means agree to accumulation round-off; memory is
             O(chunk * n * r_max) per device.
     ks:     ``None`` → all-k mode: one sort yields every k in 1..n.
-            An int → only that order statistic, via ``lax.top_k``.
+            An int → only that order statistic (``_kth_smallest``).
     record_trace: accepted for signature uniformity with ``sweep_rounds``;
             single-round sweeps have nothing to record, so ``True`` raises
             a ValueError pointing at the rounds axis.
@@ -1607,6 +1649,9 @@ class ResumableSweep:
             p0, p1 = jsums(self._base_key, starts, offs, limit, pj)
             ys = (jsamples(self._base_key, starts, offs, limit, pj)
                   if self._keep else None)
+            obs.count("engine.select_rows",
+                      (1 + self._keep) * nc_pad * self._chunk
+                      * _rank_count_columns(sig))
         with obs.span("engine.wait"):
             jax.block_until_ready((p0, p1, ys))
         with obs.span("engine.combine"):
@@ -1861,7 +1906,7 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
                 mm = jnp.take(jnp.asarray(ad_remap[i]), l_row - 1, axis=0)
                 s2 = jnp.take_along_axis(s2, mm, axis=-1)
         tau = task_arrival_times_gather(plan, s2)
-        return w_of_row, loads_w, _smallest(tau, ks)[..., -1:], tau
+        return w_of_row, loads_w, _kth_smallest(tau, ks), tau
 
     def _worker_arrivals(i, w_of_row, loads_w, s):
         """Worker-major per-message arrivals feeding the (censored)

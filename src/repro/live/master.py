@@ -6,7 +6,7 @@ scheduler, and recording every run as a replayable ``DelayTrace``.
 Authoritative statistics come from the ASSEMBLED delay tables, scored with
 the MC engine's own fused arithmetic (``_build_eval`` at the engine's
 ``(1, n, r)`` chunk shape): ``s = cumsum(T1) + T2`` (eq. 1), the gather
-plan for per-task arrivals (eq. 2), ``top_k`` for the k-th order statistic.
+plan for per-task arrivals (eq. 2), a rank count for the k-th order statistic.
 Cells never covered by a received message stay +inf — fault-censoring
 semantics, a version-2 trace.  Because the recorded tables are exactly the
 scorer's input, ``sweep_rounds(TraceProcess(result.trace), trials=1)``

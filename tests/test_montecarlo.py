@@ -4,7 +4,7 @@ Covers the ISSUE-1 acceptance points:
   (a) engine results bit-match the public simulate_* wrappers per scheme;
   (b) chunked streaming equals unchunked (per-trial subkeys make the draws
       chunking-invariant);
-  (c) the all-k output column k equals the single-k (lax.top_k) path;
+  (c) the all-k output column k equals the single-k (rank count) path;
   (d) the static gather task-arrival layout equals the scatter-min version
       on random TO matrices.
 """
@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import montecarlo as mc
 from repro.core import (cyclic_to_matrix, staircase_to_matrix,
                         random_assignment_to_matrix, scenario1, ec2_like,
                         ShiftedExponentialDelays, slot_arrival_times,
@@ -277,7 +278,7 @@ def test_sweep_rejects_bad_input():
 
 
 def test_at_k_edge_cases():
-    """SweepResult.at_k: the single-k (lax.top_k) path and the all-k (full
+    """SweepResult.at_k: the single-k (rank count) path and the all-k (full
     sort) path agree at every k on shared draws; unknown names raise."""
     n, r, trials = 8, 4, 800
     m = scenario1()
@@ -390,3 +391,25 @@ def test_pc_keeps_own_threshold_in_single_k_sweeps():
                    trials=500, seed=0, ks=k)
     assert single.at_k("pc") == allk.at_k("pc")           # k-independent
     assert pc_threshold(n, r) != k                        # and != sweep's k
+
+
+@pytest.mark.parametrize("traced", [False, True])
+@pytest.mark.parametrize("L", [1, 5, 16, mc._RANK_COUNT_MAX_WIDTH + 1])
+def test_kth_smallest_equals_sort(L, traced):
+    """The k-th order statistic, by rank count up to the width cutoff and
+    by top_k / sort above it, is bit for bit ``np.sort(x)[..., k-1]`` at
+    every k: ties, +inf sentinels and all-+inf rows included, with k a
+    Python int or a traced array under jit."""
+    rng = np.random.default_rng(L)
+    x = np.round(rng.uniform(size=(4, 6, L)) * 8) / 8       # many ties
+    x = x.astype(np.float32)
+    x[rng.uniform(size=x.shape) < 0.3] = np.inf
+    x[0, 0] = np.inf                                        # all +inf
+    x[1, 2] = x[1, 2, :1]                                   # all tied
+    ref = np.sort(x, axis=-1)
+    jk = jax.jit(mc._kth_smallest)
+    for k in range(1, L + 1):
+        got = (jk(x, jnp.full((4, 6, 1), k, jnp.int32)) if traced
+               else mc._kth_smallest(jnp.asarray(x), k))
+        assert got.shape == (4, 6, 1)
+        np.testing.assert_array_equal(np.asarray(got), ref[..., k - 1:k])

@@ -154,6 +154,25 @@ def test_sweep_records_dispatch_wait_combine(traced):
     assert traced["counters"]["engine.fetched_bytes"] > 0
 
 
+def test_sweep_counts_rank_count_rows(tmp_path, sweep11_specs):
+    """``engine.select_rows``: every dispatched (trial, scheme) row whose
+    order statistic is a rank count, padding included: seven of the
+    sweep11 mix's eleven columns (the five TO schemes and both pc; lb and
+    pcmm rank 256-wide windows).  Nothing is counted untraced."""
+    def run():
+        return sweep(sweep11_specs, MODEL, 16, trials=600, chunk=256, ks=16,
+                     seed=2)
+
+    obs.reset()
+    run()                                    # also compiles outside the trace
+    assert obs.counters() == {}
+    with jax.profiler.trace(str(tmp_path)):
+        run()
+        counted = obs.counters()["engine.select_rows"]
+    obs.reset()
+    assert counted == 3 * 256 * 7            # 600 trials pad to 3 chunks
+
+
 def test_plan_cli_profile_prints_the_spans(tmp_path, capsys):
     from repro.launch import plan as plan_cli
     rc = plan_cli.main([

@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels compile for a TPU v5e at real sizes.
+"""The main path's Pallas kernels compile for a TPU v5e at real sizes, and
+the engine's single-k order statistics compile without a narrow sort.
 
 Nothing runs: each test compiles for one chip of a described (not
 attached) ``v5e:2x2`` topology, or for its four chips under the
@@ -9,12 +10,16 @@ worker collects the same tests and only the worker given this file loads
 the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import montecarlo as mc
+from repro.core import scenario1
 from repro.kernels.ops import gram_matvec, greedy_assign
 
 
@@ -96,3 +101,24 @@ def test_greedy_assign_compiles_trial_sharded(topo, four_chips):
     assert len(topo.devices) == 4
     assert "tpu_custom_call" in compiled.as_text()
     assert "%greedy_assign" in compiled.as_text()
+
+
+def test_sweep_selection_has_no_narrow_sort(one_chip, sweep11_specs):
+    # the benchmark's sweep11 mix at k = 16: the five TO schemes' and the
+    # two pc schemes' order statistics of 16-wide axes are rank counts on
+    # the chip's compile, while the lb and pcmm windows (256 wide) keep
+    # their sorts
+    n, chunk = 16, 1024
+    sig, params, _ = mc._eval_layout(tuple(sweep11_specs), n, 16, 16)
+    fn = jax.jit(mc._build_stats_fn(sig, scenario1()))
+    compiled = fn.lower(
+        _spec((chunk, 2), jnp.uint32, one_chip),
+        {k: _spec(np.shape(v), np.asarray(v).dtype, one_chip)
+         for k, v in params.items()}).compile()
+    widths = []
+    for line in compiled.as_text().splitlines():
+        if " sort(" in line:
+            dims = re.search(r"\[([0-9,]+)\]", line).group(1).split(",")
+            (axis,) = re.search(r"dimensions=\{(\d+)\}", line).groups()
+            widths.append(int(dims[int(axis)]))
+    assert sorted(widths) == [256, 256]
