@@ -1883,17 +1883,19 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
         holding backlogged tasks in the greedy pick order."""
         sp, plan, Cb = ad_specs[i], ad_plans[i], ad_mats[i]
         # assignment uses feedback from *previous* rounds only.
-        w_of_row = scheduling.greedy_row_assignment_batch(
-            Cb, est, gamma=gamma, need=need,
-            impl=greedy_impl)                   # (chunk, n)
+        with jax.named_scope("engine.greedy"):
+            w_of_row = scheduling.greedy_row_assignment_batch(
+                Cb, est, gamma=gamma, need=need,
+                impl=greedy_impl)               # (chunk, n)
         # row p's slots are executed by worker w_of_row[p]: permute the
         # worker axis, then the static gather plan applies.
         s2 = jnp.take_along_axis(s, w_of_row[..., None], axis=1)
         loads_w = None
         if sp.rebalance:
             r_sp = Cb.shape[1]
-            loads_w = scheduling.greedy_load_rebalance_batch(
-                est, ad_l0[i], r_max=r_sp, min_load=1)       # (chunk, n)
+            with jax.named_scope("engine.rebalance"):
+                loads_w = scheduling.greedy_load_rebalance_batch(
+                    est, ad_l0[i], r_max=r_sp, min_load=1)   # (chunk, n)
             # row p inherits its executor's load: mask the trailing slots
             # of the row-major grid to +inf before the static gather.
             l_row = jnp.take_along_axis(loads_w, w_of_row, axis=-1)
@@ -2286,26 +2288,45 @@ def _run_rounds(specs, process, n, *, rounds: int, k: int, trials: int,
                           greedy_impl=greedy_impl)
         return out[:-1] + (trace,)
 
-    devs, nc_pad, padded = _shard_layout(trials, chunk, devices)
-    jsums, jsamples = _get_rounds_exec(
-        specs, process, n, r_max, k, rounds, beta, gamma, censored,
-        deadline, deadline_policy, devs, greedy_impl)
+    with obs.span("engine.dispatch"):
+        devs, nc_pad, padded = _shard_layout(trials, chunk, devices)
+        jsums, jsamples = _get_rounds_exec(
+            specs, process, n, r_max, k, rounds, beta, gamma, censored,
+            deadline, deadline_policy, devs, greedy_impl)
+        # (trial, round) rows the greedy and the re-balance solve,
+        # padding included
+        obs.count("engine.greedy_rows", padded * rounds * sum(
+            sp.kind == "adaptive" for sp in specs))
+        obs.count("engine.rebalance_rows", padded * rounds * sum(
+            sp.rebalance for sp in specs))
 
-    # the scans derive per-trial keys AND trial ids device-side from the
-    # (base key, per-chunk start) coordinates: padded lanes replay a valid
-    # (clamped) trial id — deriving the last real trial's key, exactly the
-    # ``_padded_keys`` reference twin — and are masked out of every
-    # statistic below, so trace replay stays invariant to chunking AND
-    # sharding without a host key table.
-    base_key = jax.random.PRNGKey(seed)
-    starts, offs, limit = _scan_coords(trials, chunk, nc_pad)
+        # the scans derive per-trial keys AND trial ids device-side from
+        # the (base key, per-chunk start) coordinates: padded lanes replay
+        # a valid (clamped) trial id — deriving the last real trial's key,
+        # exactly the ``_padded_keys`` reference twin — and are masked out
+        # of every statistic below, so trace replay stays invariant to
+        # chunking AND sharding without a host key table.
+        base_key = jax.random.PRNGKey(seed)
+        starts, offs, limit = _scan_coords(trials, chunk, nc_pad)
+        out = (jsamples if want_samples else jsums)(base_key, starts, offs,
+                                                    limit)
+    with obs.span("engine.wait"):
+        jax.block_until_ready(out)
 
     if want_samples:
-        ys = jsamples(base_key, starts, offs, limit)
-        return ({nm: jnp.moveaxis(v, 1, -1).reshape(padded, rounds)[:trials]
-                 for nm, v in ys.items()}, None)  # (nc,R,chunk)->(trials,R)
+        with obs.span("engine.combine"):
+            return ({nm: jnp.moveaxis(v, 1, -1).reshape(padded,
+                                                         rounds)[:trials]
+                     for nm, v in out.items()}, None)  # ->(trials, R)
 
-    s0, s1, c0, c1, ac = jsums(base_key, starts, offs, limit)
+    with obs.span("engine.combine"):
+        return _rounds_moments(out, trials, deadline)
+
+
+def _rounds_moments(parts, trials: int, deadline: Optional[float]):
+    """Per-round and wall-clock means and standard errors (and the
+    degradation streams) from the rounds scan's per-chunk partials."""
+    s0, s1, c0, c1, ac = parts
 
     def moments(parts0, parts1):
         # per-chunk float32 partials -> float64 in global chunk order: the
@@ -2404,6 +2425,7 @@ class RoundsResult:
         return self._degr(name, "khist")
 
 
+@obs.span("engine.rounds")
 def sweep_rounds(specs: Sequence[SchemeSpec], process, n: int, *,
                  rounds: int, k: int, trials: int = 20000, seed: int = 0,
                  chunk: Optional[int] = None, feedback_beta: float = 0.7,

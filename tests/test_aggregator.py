@@ -95,12 +95,18 @@ class TestAggregator:
         spec = RoundSpec(n=8, r=4, k=6)
         proc = ec2_cluster(8, spread=2.0, persistence=0.9)
         agg = StragglerAggregator(spec, proc)
-        t = agg.expected_completion(trials=512)
+        t = agg.expected_completion(trials=8192)
         t2 = agg.expected_completion(trials=512, rounds=3)
         assert 0 < t and 0 < t2
         ad = StragglerAggregator(spec, proc, adaptive=True)
-        t_ad = ad.expected_completion(trials=512)
-        assert 0 < t_ad < t                      # adaptive helps here
+        t_ad = ad.expected_completion(trials=8192)
+        # adaptive helps here: the plain reference of bench/refs/
+        # adaptive_round.py puts the gap at 2.98e-6 s (about 41 standard
+        # errors at 131,072 trials).  Both estimates share their draws;
+        # at 8,192 trials the gap is about ten standard errors of the
+        # paired difference (at 512 it is 2.6, and seed 0 reads it
+        # reversed).
+        assert 0 < t_ad < t
 
     @settings(deadline=None, max_examples=20)
     @given(st.integers(2, 8), st.data())

@@ -187,3 +187,53 @@ def test_plan_cli_profile_prints_the_spans(tmp_path, capsys):
     assert rows["plan.decide"][0] == "1" and rows["plan.rung"][0] == "3"
     assert list(tmp_path.glob("trace/plugins/profile/*/*.xplane.pb"))
     obs.reset()
+
+
+def _rounds():
+    from repro.core import adaptive_spec, ec2_cluster, sweep_rounds
+    specs = [to_spec("cs", cyclic_to_matrix(N, 2)),
+             adaptive_spec("adapt", cyclic_to_matrix(N, 2)),
+             adaptive_spec("rebal", cyclic_to_matrix(N, 4), loads=(2,) * N,
+                           rebalance=True)]
+    return sweep_rounds(specs, ec2_cluster(N, persistence=0.9), N, rounds=3,
+                        k=6, trials=600, chunk=256, seed=4,
+                        censored_feedback=True)
+
+
+@pytest.fixture(scope="module")
+def traced_rounds(tmp_path_factory):
+    """One ``sweep_rounds`` under a profiler session, and one without."""
+    untraced = _rounds()                    # also compiles outside the trace
+    obs.reset()
+    with jax.profiler.trace(str(tmp_path_factory.mktemp("rounds"))):
+        res = _rounds()
+        out = {"res": res, "untraced": untraced, "spans": obs.spans(),
+               "counters": obs.counters()}
+    obs.reset()
+    return out
+
+
+def test_rounds_records_dispatch_wait_combine(traced_rounds):
+    recs = traced_rounds["spans"]
+    (root,) = [s for s in recs if s.parent is None]
+    assert root.name == "engine.rounds"
+    kids = sorted((s for s in recs if s.parent == root.id),
+                  key=lambda s: s.start_ns)
+    assert [s.name for s in kids] == ["engine.dispatch", "engine.wait",
+                                      "engine.combine"]
+    assert all(s.request == root.id for s in recs)
+
+
+def test_rounds_counts_greedy_and_rebalance_rows(traced_rounds):
+    # 600 trials pad to 3 chunks of 256; 3 rounds; two adaptive schemes,
+    # one of them re-balancing
+    got = traced_rounds["counters"]
+    assert got["engine.greedy_rows"] == 3 * 256 * 3 * 2
+    assert got["engine.rebalance_rows"] == 3 * 256 * 3 * 1
+
+
+def test_rounds_same_with_tracing_on_and_off(traced_rounds):
+    on, off = traced_rounds["res"], traced_rounds["untraced"]
+    for field in ("per_round", "stderr", "wallclock", "wallclock_stderr"):
+        for name, v in getattr(off, field).items():
+            np.testing.assert_array_equal(getattr(on, field)[name], v)

@@ -122,3 +122,35 @@ def test_sweep_selection_has_no_narrow_sort(one_chip, sweep11_specs):
             (axis,) = re.search(r"dimensions=\{(\d+)\}", line).groups()
             widths.append(int(dims[int(axis)]))
     assert sorted(widths) == [256, 256]
+
+
+@pytest.mark.parametrize("chunk,rounds", [(16384, 20)])
+def test_rounds_program_compiles(one_chip, monkeypatch, chunk, rounds):
+    # the benchmark's adaptive20 mix on the s1-n16-markov cluster: one
+    # chunk of the rounds scan compiles for the chip, and both adaptive
+    # schemes' greedy is the Mosaic kernel inside the engine.greedy scope
+    from repro.core import (MarkovRegimeProcess, adaptive_spec,
+                            cyclic_to_matrix, heterogeneous_scales, lb_spec,
+                            to_spec)
+    from repro.kernels import gram_matvec
+    monkeypatch.setattr(gram_matvec, "default_interpret", lambda **kw: False)
+    n, k = 16, 12
+    specs = mc._check_rounds_args(
+        [to_spec("cs4", cyclic_to_matrix(n, 4)),
+         adaptive_spec("adapt4", cyclic_to_matrix(n, 4)),
+         adaptive_spec("rebal4", cyclic_to_matrix(n, 8), loads=(4,) * n,
+                       rebalance=True),
+         lb_spec(4, name="lb4")], n, k, rounds)
+    process = MarkovRegimeProcess(
+        base=scenario1(), worker_scale=heterogeneous_scales(n, 3.0, 1),
+        p_slow=0.25, persistence=0.95, slow=8.0)
+    fn = mc._build_rounds_fn(specs, process, n, 8, k, rounds, 0.7, 0.5,
+                             True, greedy_impl="kernel")
+    text = jax.jit(fn).lower(_spec((chunk, 2), jnp.uint32, one_chip),
+                             _spec((chunk,), jnp.int32, one_chip)
+                             ).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and "%greedy_assign" in ln]
+    assert len(calls) == 2
+    assert all("engine.greedy" in ln for ln in calls)
+    assert "engine.rebalance" in text
