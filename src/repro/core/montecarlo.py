@@ -1878,9 +1878,11 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
         """Greedy row re-assignment (and, for rebalance specs, greedy load
         re-allocation) from ``est`` feedback, then this scheme's completion
         time on the permuted (and masked) slot grid.  Returns
-        ``(w_of_row, loads_w, val, tau)`` with ``loads_w`` None for
-        fixed-load specs.  ``need`` (reissue policy) prioritizes rows
-        holding backlogged tasks in the greedy pick order."""
+        ``(w_of_row, loads_w, val, tau, tie_rows)`` with ``loads_w`` None
+        for fixed-load specs and ``tie_rows`` the chunk's rows where the
+        re-balance ran its descent (0 for fixed-load specs).  ``need``
+        (reissue policy) prioritizes rows holding backlogged tasks in the
+        greedy pick order."""
         sp, plan, Cb = ad_specs[i], ad_plans[i], ad_mats[i]
         # assignment uses feedback from *previous* rounds only.
         with jax.named_scope("engine.greedy"):
@@ -1890,12 +1892,16 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
         # row p's slots are executed by worker w_of_row[p]: permute the
         # worker axis, then the static gather plan applies.
         s2 = jnp.take_along_axis(s, w_of_row[..., None], axis=1)
-        loads_w = None
+        loads_w, tie_rows = None, 0
         if sp.rebalance:
             r_sp = Cb.shape[1]
             with jax.named_scope("engine.rebalance"):
                 loads_w = scheduling.greedy_load_rebalance_batch(
                     est, ad_l0[i], r_max=r_sp, min_load=1)   # (chunk, n)
+                # the same sort as the closed form's: XLA merges the two
+                tied = scheduling._rebalance_fixed_point(est, ad_l0[i],
+                                                         r_sp, 1)[1]
+                tie_rows = jnp.where(tied.any(), est.shape[0], 0)
             # row p inherits its executor's load: mask the trailing slots
             # of the row-major grid to +inf before the static gather.
             l_row = jnp.take_along_axis(loads_w, w_of_row, axis=-1)
@@ -1908,7 +1914,7 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
                 mm = jnp.take(jnp.asarray(ad_remap[i]), l_row - 1, axis=0)
                 s2 = jnp.take_along_axis(s2, mm, axis=-1)
         tau = task_arrival_times_gather(plan, s2)
-        return w_of_row, loads_w, _kth_smallest(tau, ks), tau
+        return w_of_row, loads_w, _kth_smallest(tau, ks), tau, tie_rows
 
     def _worker_arrivals(i, w_of_row, loads_w, s):
         """Worker-major per-message arrivals feeding the (censored)
@@ -1984,10 +1990,12 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
                             times, aux):
             """One adaptive scheme's round: assign (+ reissue priority),
             score, apply the deadline policy, update the reissue backlog /
-            need.  Returns what the censored feedback update needs."""
+            need.  Returns what the censored feedback update needs, and
+            the re-balance's descent rows."""
             sp = ad_specs[i]
             need = needs.get(sp.name) if reissue else None
-            w_of_row, loads_w, val, tau = _assign_and_score(i, est, s, need)
+            w_of_row, loads_w, val, tau, tie_rows = _assign_and_score(
+                i, est, s, need)
             v = val[..., 0]
             if DL is None:
                 by = dv = None
@@ -2004,7 +2012,7 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
                 owed = (new_backs[sp.name] > 0)[..., None]
                 new_needs[sp.name] = (~delivered & owed).astype(jnp.float32)
             times[sp.name] = v_eff
-            return w_of_row, loads_w, v_eff
+            return w_of_row, loads_w, v_eff, tie_rows
 
         # NB: the round index rides the scan xs (an ``arange``) instead of
         # an integer carry — numerically identical, and immune to a
@@ -2028,12 +2036,13 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
                     times[sp.name] = v_eff
                     if a is not None:
                         aux[sp.name] = a
-                new_e = []
+                new_e, ties = [], 0
                 for i, (sp, Cb, est) in enumerate(zip(ad_specs, ad_mats,
                                                       ests)):
-                    w_of_row, loads_w, v_eff = _adaptive_round(
+                    w_of_row, loads_w, v_eff, tie_rows = _adaptive_round(
                         i, est, s, needs, backs, new_backs, new_needs,
                         times, aux)
+                    ties = ties + tie_rows
                     r_sp = Cb.shape[1]
                     # shared censored update: only messages that beat this
                     # scheme's own round close are observed (the deadline
@@ -2041,8 +2050,8 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
                     arr_w = _worker_arrivals(i, w_of_row, loads_w, s)
                     new_e.append(scheduling.censored_feedback_update(
                         est, T1[..., :r_sp], arr_w, v_eff, beta=beta))
-                return (pstate, tuple(new_e), new_needs, new_backs), (times,
-                                                                      aux)
+                return ((pstate, tuple(new_e), new_needs, new_backs),
+                        (times, aux, jnp.int32(ties)))
 
             init = (pstate,
                     tuple(jnp.full((chunk, n), INF, jnp.float32)
@@ -2063,9 +2072,11 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
                     times[sp.name] = v_eff
                     if a is not None:
                         aux[sp.name] = a
+                ties = 0
                 for i in range(len(ad_specs)):
-                    _adaptive_round(i, est, s, needs, backs, new_backs,
-                                    new_needs, times, aux)
+                    ties = ties + _adaptive_round(
+                        i, est, s, needs, backs, new_backs, new_needs,
+                        times, aux)[-1]
                 obs = T1.mean(axis=-1)              # per-worker compute time
                 # +inf-safe: a fault-censored worker's +inf observation
                 # keeps the previous estimate (EMAing it would pin est at
@@ -2073,7 +2084,8 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
                 fin = jnp.isfinite(obs)
                 upd = jnp.where(t == 0, obs, beta * est + (1.0 - beta) * obs)
                 est = jnp.where(fin, upd, est)
-                return (pstate, est, new_needs, new_backs), (times, aux)
+                return ((pstate, est, new_needs, new_backs),
+                        (times, aux, jnp.int32(ties)))
 
             init = (pstate, jnp.ones((chunk, n), jnp.float32),
                     needs0, backs0)
@@ -2082,7 +2094,9 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
             _, ys = jax.lax.scan(body, init,
                                  (jnp.swapaxes(allk[:, 1:], 0, 1),
                                   jnp.arange(rounds, dtype=jnp.int32)))
-        return ys             # ({name: (rounds, chunk)}, {name: aux dicts})
+        # ({name: (rounds, chunk)}, {name: aux dicts}, (rounds,) the
+        # chunk's (trial, round) rows where a re-balance ran its descent)
+        return ys
 
     return rounds_fn
 
@@ -2140,7 +2154,7 @@ def _get_rounds_exec(specs: Tuple[SchemeSpec, ...], process, n: int,
         def body(carry, start):
             tids_raw = start + offs
             tc = jnp.minimum(tids_raw, limit - 1)
-            ys, aux = rounds_fn(_fold_keys(base_key, tc), tc)
+            ys, aux, ties = rounds_fn(_fold_keys(base_key, tc), tc)
             vd = tids_raw < limit
             ok = vd[None, :]
             cum = {k2: jnp.cumsum(v, axis=0) for k2, v in ys.items()}
@@ -2151,21 +2165,22 @@ def _get_rounds_exec(specs: Tuple[SchemeSpec, ...], process, n: int,
             c1 = {k2: jnp.where(ok, jnp.square(cum[k2]), 0.0).sum(axis=1)
                   for k2 in cum}
             ac = _chunk_aux(aux, vd) if has_dl else {}
-            return carry, (s0, s1, c0, c1, ac)
+            return carry, (s0, s1, c0, c1, ac, ties.sum())
 
         _, parts = jax.lax.scan(body, None, starts)
-        return parts          # 4 x {name: (nc, rounds)} + degradation
+        return parts   # 4 x {name: (nc, rounds)}, degradation, (nc,) ties
 
     def samples_scan(base_key, starts, offs, limit):
         _count_trace()
 
         def body(carry, start):
             tc = jnp.minimum(start + offs, limit - 1)
-            # times only (aux is DCE'd)
-            return carry, rounds_fn(_fold_keys(base_key, tc), tc)[0]
+            # times and ties only (aux is DCE'd)
+            ys, _, ties = rounds_fn(_fold_keys(base_key, tc), tc)
+            return carry, (ys, ties.sum())
 
-        _, ys = jax.lax.scan(body, None, starts)
-        return ys             # {name: (nc, R, chunk)}
+        _, out = jax.lax.scan(body, None, starts)
+        return out            # ({name: (nc, R, chunk)}, (nc,) ties)
 
     if len(devices) > 1:
         # shard_trials returns a fully-jitted callable; no outer jit.
@@ -2313,13 +2328,14 @@ def _run_rounds(specs, process, n, *, rounds: int, k: int, trials: int,
     with obs.span("engine.wait"):
         jax.block_until_ready(out)
 
-    if want_samples:
-        with obs.span("engine.combine"):
+    *out, ties = out
+    with obs.span("engine.combine"):
+        if obs.enabled():
+            obs.count("engine.rebalance_tie_rows", int(np.sum(ties)))
+        if want_samples:
             return ({nm: jnp.moveaxis(v, 1, -1).reshape(padded,
                                                          rounds)[:trials]
-                     for nm, v in out.items()}, None)  # ->(trials, R)
-
-    with obs.span("engine.combine"):
+                     for nm, v in out[0].items()}, None)  # ->(trials, R)
         return _rounds_moments(out, trials, deadline)
 
 

@@ -459,9 +459,10 @@ def greedy_load_rebalance(speed_est, loads=None, *, total: int | None = None,
     estimates on an uneven allocation still descend toward the even split
     (that is the makespan greedy doing its job).
 
-    Returns the new per-worker load vector (int64).  Delegates to the
-    batched JAX implementation (one source of truth with the fused rounds
-    engine).
+    Returns the new per-worker load vector (int64): the descent's fixed
+    point.  Delegates to the batched JAX implementation (one source of
+    truth with the fused rounds engine), which computes that fixed point
+    in closed form.
     """
     if loads is None:
         if total is None or speed_est is None:
@@ -502,18 +503,85 @@ def greedy_load_rebalance_batch(est: jax.Array, loads: np.ndarray, *,
                                 r_max: int, min_load: int = 1,
                                 steps: int | None = None) -> jax.Array:
     """Batched JAX twin of ``greedy_load_rebalance``: ``est`` has shape
-    (..., n) (``+inf`` = never observed), ``loads`` is the static initial
-    allocation; returns per-worker loads of the same batch shape (int32),
-    each summing to ``sum(loads)``.  Pure and jit/scan-friendly; used
-    per-round inside the fused rounds engine.  ``steps`` bounds the number
-    of single-slot moves (default ``n * r_max``, enough to reach the greedy
-    fixed point from any allocation); once no strictly improving move
-    exists the allocation is a no-op fixed point, so extra steps are
-    harmless."""
+    (..., n) (non-negative delay estimates, ``+inf`` = never observed),
+    ``loads`` is the static initial allocation; returns per-worker loads of
+    the same batch shape (int32), each summing to ``sum(loads)``.  Pure and
+    jit/scan-friendly; used per-round inside the fused rounds engine.
+
+    The makespan descent (``_rebalance_descent``) moves a slot only when
+    that strictly lowers a cost, so no slot moves twice and it reaches its
+    fixed point within ``n * (r_max - min_load)`` moves.  There, every held
+    extra slot costs no more than any unheld one: the held set is the
+    ``T = sum(loads) - n * min_load`` cheapest of the slot costs
+    ``est[w] * j``, ``j`` in ``(min_load, r_max]`` (the float32 products
+    the descent forms).  This computes that fixed point with one sort per
+    row: each worker holds ``min_load`` plus its slots costing at most the
+    T-th cheapest.  Where the T-th cheapest is ``+inf`` the observed
+    workers cannot hold the total, and the descent's donors (the lowest
+    index first among the ``+inf`` finishers) leave the unobserved workers
+    their initial loads from the highest index down.
+
+    A finite tie at the threshold (T-th cheapest == (T+1)-th) makes the
+    descent's result depend on its path; where any row of the batch has
+    one, ``lax.cond`` runs the descent for the whole batch instead, so the
+    result equals the descent's bit for bit on every input.  ``steps``
+    bounds the descent's single-slot moves (default ``n * r_max``); below
+    ``n * (r_max - min_load)`` a truncated descent has no closed form, and
+    it runs alone."""
     loads = np.asarray(loads, np.int64)
     n = loads.shape[0]
+    if not min_load <= loads.min() <= loads.max() <= r_max:
+        raise ValueError(f"need {min_load} <= loads <= r_max={r_max}; got "
+                         f"{loads.tolist()}")
     if steps is None:
         steps = int(n * r_max)
+    batch = est.shape[:-1]
+    flat = est.reshape((-1, n))
+    if steps < n * (r_max - min_load):
+        out = _rebalance_descent(flat, loads, r_max, min_load, steps)
+    else:
+        closed, tied = _rebalance_fixed_point(flat, loads, r_max, min_load)
+        out = jax.lax.cond(
+            tied.any(),
+            lambda: _rebalance_descent(flat, loads, r_max, min_load, steps),
+            lambda: closed)
+    return out.reshape(batch + (n,))
+
+
+def _rebalance_fixed_point(est, loads: np.ndarray, r_max: int,
+                           min_load: int):
+    """The descent's fixed point in closed form, ``est`` (B, n): returns
+    ``(loads (B, n) int32, tied (B,) bool)``, the loads being the
+    descent's wherever ``tied`` is False."""
+    B, n = est.shape
+    m = r_max - min_load                      # extra slots a worker may hold
+    T = int(loads.sum()) - n * min_load       # extra slots held in all
+    l0 = jnp.broadcast_to(jnp.asarray(loads, jnp.int32), (B, n))
+    if T == 0 or T == n * m:                  # nothing can move
+        return l0, jnp.zeros((B,), bool)
+    j = jnp.arange(min_load + 1, r_max + 1, dtype=jnp.float32)
+    cost = est[:, :, None] * j                # (B, n, m), as the descent
+    srt = jnp.sort(cost.reshape(B, n * m), axis=-1)
+    thr, nxt = srt[:, T - 1], srt[:, T]
+    fin = jnp.isfinite(cost)
+    take = ((cost <= thr[:, None, None]) & fin).sum(-1)
+    # thr = +inf: the finite slots are all held, and the total beyond them
+    # stays with the +inf finishers, shed from the lowest index first.
+    nfin = fin.sum(-1)
+    short = T - nfin.sum(-1)
+    room = jnp.maximum(l0 - min_load - nfin, 0)
+    above = jnp.cumsum(room[:, ::-1], axis=-1)[:, ::-1] - room
+    keep = jnp.clip(short[:, None] - above, 0, room)
+    out = (min_load + take + keep).astype(jnp.int32)
+    return out, jnp.isfinite(thr) & (thr == nxt)
+
+
+def _rebalance_descent(est, loads: np.ndarray, r_max: int, min_load: int,
+                       steps: int):
+    """The makespan descent, ``est`` (B, n): ``steps`` times, move one slot
+    from the largest finisher ``est[d] * loads[d]`` to the cheapest extra
+    slot ``est[w] * (loads[w] + 1)`` when that strictly lowers the cost,
+    within ``min_load <= loads <= r_max``."""
     l0 = jnp.asarray(loads, jnp.int32)
     ninf = jnp.float32(-np.inf)
     pinf = jnp.float32(np.inf)
@@ -538,10 +606,7 @@ def greedy_load_rebalance_batch(est: jax.Array, loads: np.ndarray, *,
         l, _ = jax.lax.scan(move, l0, None, length=steps)
         return l
 
-    batch = est.shape[:-1]
-    flat = est.reshape((-1, n))
-    out = jax.vmap(one)(flat)
-    return out.reshape(batch + (n,))
+    return jax.vmap(one)(est)
 
 
 def censored_feedback_update(est: jax.Array, t1: jax.Array,

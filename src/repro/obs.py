@@ -10,7 +10,7 @@ same gate.  With no session active a span costs one ``is_enabled`` check
 and records nothing, so untraced runs keep nothing.
 
 ``spans()``, ``counters()`` and ``summary()`` read what was recorded;
-``reset()`` clears it.  ``python -m repro.launch.plan --profile DIR``
+``reset()`` clears it; ``enabled()`` says whether the gate is open.  ``python -m repro.launch.plan --profile DIR``
 prints the summary of one decision.
 """
 from __future__ import annotations
@@ -88,6 +88,12 @@ def span(name: str, **attrs):
                     _spans.append(rec)
             if full:
                 _add("obs.dropped", 1)
+
+
+def enabled() -> bool:
+    """Whether a profiler session is active: spans are recorded and
+    counters add."""
+    return _is_enabled()
 
 
 def count(name: str, n) -> None:

@@ -232,6 +232,40 @@ def test_rounds_counts_greedy_and_rebalance_rows(traced_rounds):
     assert got["engine.rebalance_rows"] == 3 * 256 * 3 * 1
 
 
+def test_rounds_counts_no_tie_rows_on_continuous_estimates(traced_rounds):
+    # EMAs of continuous draws never tie at the re-balance's threshold
+    assert traced_rounds["counters"]["engine.rebalance_tie_rows"] == 0
+
+
+def test_rounds_counts_tie_rows_where_estimates_tie(tmp_path):
+    """Delays equal across workers give equal estimates every round; on
+    an uneven budget (17 slots over 8 workers) the threshold then falls
+    inside a level of equal slot costs, and every chunk-round takes the
+    descent.  Tracing on and off give identical results."""
+    from repro.core import DelayTrace, TraceProcess, adaptive_spec, sweep_rounds
+    rounds = 3
+    ones = np.ones((rounds, N, 4), np.float32)
+    process = TraceProcess(DelayTrace(ones, ones))
+
+    def run():
+        return sweep_rounds(
+            [adaptive_spec("rebal", cyclic_to_matrix(N, 4),
+                           loads=(3,) + (2,) * (N - 1), rebalance=True)],
+            process, N, rounds=rounds, k=6, trials=600, chunk=256, seed=4)
+
+    obs.reset()
+    off = run()
+    with jax.profiler.trace(str(tmp_path)):
+        on = run()
+        got = obs.counters()
+    obs.reset()
+    assert got["engine.rebalance_tie_rows"] == 3 * 256 * rounds
+    assert got["engine.rebalance_rows"] == 3 * 256 * rounds
+    for field in ("per_round", "stderr", "wallclock", "wallclock_stderr"):
+        np.testing.assert_array_equal(getattr(on, field)["rebal"],
+                                      getattr(off, field)["rebal"])
+
+
 def test_rounds_same_with_tracing_on_and_off(traced_rounds):
     on, off = traced_rounds["res"], traced_rounds["untraced"]
     for field in ("per_round", "stderr", "wallclock", "wallclock_stderr"):

@@ -308,6 +308,73 @@ class TestGreedyLoadRebalance:
             out = greedy_load_rebalance(est, loads, r_max=8)
             assert (est * out).max() <= (est * loads).max() + 1e-6
 
+    @pytest.mark.parametrize("n,cap,min_load,pattern", [
+        (3, 2, 1, "none"), (4, 3, 1, "some"), (6, 5, 2, "none"),
+        (7, 8, 1, "all"), (9, 6, 2, "some"), (12, 4, 1, "dead_at_cap"),
+        (16, 8, 1, "some"), (16, 8, 2, "dead_at_cap"), (5, 4, 1, "equal"),
+        (10, 7, 2, "products"), (16, 8, 1, "products")],
+        ids=lambda v: str(v))
+    def test_closed_form_is_the_descent(self, n, cap, min_load, pattern):
+        """The closed form equals the makespan descent bit for bit on every
+        row without a finite tie at its threshold; where a row has one, the
+        batch takes the descent, so the function equals it everywhere."""
+        from repro.core.scheduling import (_rebalance_descent,
+                                           _rebalance_fixed_point)
+        rng = np.random.default_rng(n * 100 + cap * 10 + min_load)
+        B = 48
+        loads = rng.integers(min_load, cap + 1, n)      # uneven
+        est = rng.uniform(0.05, 2.0, (B, n)).astype(np.float32)
+        extra = int(loads.sum()) - n * min_load
+        if pattern == "some":
+            est[rng.random((B, n)) < 0.3] = np.inf
+        elif pattern == "all":
+            est[::2] = np.inf
+            est[1::4, : n // 2] = np.inf
+        elif pattern == "dead_at_cap":
+            # the live workers' room (cap - min_load each) just below, at
+            # and just above the total the dead ones must hand over
+            live0 = extra // (cap - min_load)
+            for b in range(B):
+                live = int(np.clip(live0 + b % 3 - 1, 0, n))
+                est[b, rng.permutation(n)[live:]] = np.inf
+        elif pattern == "equal":
+            est[:] = rng.uniform(0.05, 2.0, (B, 1))
+        elif pattern == "products":
+            # e_a * i == e_b * j for small whole slot numbers
+            est = (rng.choice([2.0, 3.0, 4.0, 6.0], (B, n)) / 8
+                   ).astype(np.float32)
+        e = jnp.asarray(est)
+        want = np.asarray(_rebalance_descent(e, loads, cap, min_load,
+                                             n * cap))
+        closed, tied = map(np.asarray, _rebalance_fixed_point(
+            e, loads, cap, min_load))
+        np.testing.assert_array_equal(closed[~tied], want[~tied])
+        got = np.asarray(greedy_load_rebalance_batch(e, loads, r_max=cap,
+                                                     min_load=min_load))
+        np.testing.assert_array_equal(got, want)
+        assert got.dtype == np.int32 and (got.sum(-1) == loads.sum()).all()
+        assert tied.any() == (pattern in ("equal", "products"))
+
+    def test_tie_at_the_threshold_takes_the_descent(self):
+        """Equal estimates on [1, 1, 3] under cap 4: the descent moves one
+        slot from the last worker to the first, where the cheapest slots
+        alone could give either of the first two."""
+        from repro.core.scheduling import _rebalance_fixed_point
+        e = jnp.ones((1, 3), jnp.float32)
+        assert bool(_rebalance_fixed_point(e, np.array([1, 1, 3]), 4, 1)[1])
+        got = greedy_load_rebalance_batch(e, [1, 1, 3], r_max=4)
+        assert np.asarray(got).tolist() == [[2, 1, 2]]
+
+    def test_truncated_descent_runs_alone(self):
+        """``steps`` below ``n * (cap - min_load)`` stops the descent
+        short of its fixed point, and the function returns that."""
+        est = jnp.asarray([[1.0, 1.0, 1.0, 9.0]], jnp.float32)
+        got = greedy_load_rebalance_batch(est, [2, 2, 2, 4], r_max=4,
+                                          steps=1)
+        assert np.asarray(got).tolist() == [[3, 2, 2, 3]]
+        full = greedy_load_rebalance_batch(est, [2, 2, 2, 4], r_max=4)
+        assert np.asarray(full).tolist() == [[3, 3, 3, 1]]
+
     def test_validation(self):
         with pytest.raises(ValueError, match="sum"):
             greedy_load_rebalance(np.ones(4), [2] * 4, total=9, r_max=4)

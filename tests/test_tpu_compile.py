@@ -128,7 +128,10 @@ def test_sweep_selection_has_no_narrow_sort(one_chip, sweep11_specs):
 def test_rounds_program_compiles(one_chip, monkeypatch, chunk, rounds):
     # the benchmark's adaptive20 mix on the s1-n16-markov cluster: one
     # chunk of the rounds scan compiles for the chip, and both adaptive
-    # schemes' greedy is the Mosaic kernel inside the engine.greedy scope
+    # schemes' greedy is the Mosaic kernel inside the engine.greedy scope;
+    # the re-balance's descent sits in a conditional, not a select of both
+    # branches, beside the closed form's one sort (which the tie counter
+    # shares)
     from repro.core import (MarkovRegimeProcess, adaptive_spec,
                             cyclic_to_matrix, heterogeneous_scales, lb_spec,
                             to_spec)
@@ -154,3 +157,6 @@ def test_rounds_program_compiles(one_chip, monkeypatch, chunk, rounds):
     assert len(calls) == 2
     assert all("engine.greedy" in ln for ln in calls)
     assert "engine.rebalance" in text
+    rebalance = [ln for ln in text.splitlines() if "engine.rebalance" in ln]
+    assert len([ln for ln in rebalance if " conditional(" in ln]) == 1
+    assert len([ln for ln in rebalance if " sort(" in ln]) == 1
