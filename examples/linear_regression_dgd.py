@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import regression_config
-from repro.core import (RoundSpec, StragglerAggregator, as_process,
+from repro.core import (RoundConfig, StragglerAggregator, as_process,
                         ec2_cluster, ec2_like, slot_arrival_times,
                         pc_encode, pc_worker_compute, pc_decode,
                         pc_threshold, pcmm_encode, pcmm_worker_compute,
@@ -37,14 +37,14 @@ def loss_of(theta, X, y):
 
 
 def run_uncoded(spec, process, Xs_cols, Xty_parts, N, X, y, iters, lr, *,
-                adaptive=False, curve_every=10, label="?", seed=0):
+                curve_every=10, label="?", seed=0):
     """The paper's uncoded DGD loop (Table I rows) through the round API:
     the aggregator holds the cluster's straggler state across iterations
     and (optionally) re-permutes the schedule rows from delay feedback."""
-    n, r = spec.n, spec.r
+    n, r = spec.n, spec.width
     d = Xs_cols.shape[1]
     theta = np.zeros(d, np.float32)
-    agg = StragglerAggregator(spec, process, adaptive=adaptive)
+    agg = StragglerAggregator(spec, process)
     key = jax.random.PRNGKey(seed)
     clock, curve = 0.0, []
     for it in range(iters):
@@ -101,11 +101,10 @@ def main():
     for name, sched, adaptive in (("CS", "cs", False), ("SS", "ss", False),
                                   ("RA", "ra", False),
                                   ("ADAPT", "cs", True)):
-        spec = RoundSpec(n=n, r=n if sched == "ra" else r, k=k,
-                         schedule=sched)
+        spec = RoundConfig(n=n, k=k, kind=sched,
+                           r=n if sched == "ra" else r, adaptive=adaptive)
         theta, clock = run_uncoded(spec, process, Xs_cols, Xty_parts, N,
-                                   X, y, args.iters, lr, adaptive=adaptive,
-                                   label=name)
+                                   X, y, args.iters, lr, label=name)
         rows.append((name, loss_of(theta, X, y), clock))
 
     # --- PC: one coded message per worker, threshold 2*ceil(n/r)-1 --------

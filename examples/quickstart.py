@@ -9,7 +9,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax
 import numpy as np
 
-from repro.core import (RoundSpec, adaptive_spec, cyclic_to_matrix,
+from repro.core import (RoundConfig, adaptive_spec, cyclic_to_matrix,
                         ec2_cluster, lb_spec, mean_completion_time,
                         random_assignment_to_matrix, scenario1,
                         simulate_lower_bound, staircase_to_matrix, sweep,
@@ -72,7 +72,7 @@ def main():
                       param_dtype="float32", dtype="float32", remat=False)
     opt = adamw(1e-3)
     state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    spec = RoundSpec(n=n, r=r, k=k, schedule="ss")
+    spec = RoundConfig(n=n, k=k, kind="ss", r=r)
     part = TaskPartition(n=n, global_batch=n, seq_len=32, vocab=256,
                         source="bigram")
     step = jax.jit(make_straggler_train_step(cfg, opt, spec, model))

@@ -26,8 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (AR1Process, AdaptiveScheduler, BimodalStragglerDelays,
-                        RoundSpec, ec2_cluster, heterogeneous_scales,
+from repro.core import (AR1Process, BimodalStragglerDelays, RoundConfig,
+                        ec2_cluster, heterogeneous_scales, make_scheduler,
                         scenario1)
 from repro.data import TaskPartition, lm_task_batches
 from repro.models import ModelConfig, num_params
@@ -94,7 +94,8 @@ def main():
         adaptive = sched == "adaptive"
         base = "cs" if adaptive else sched
         r = args.n if base == "ra" else args.r
-        spec = RoundSpec(n=args.n, r=r, k=args.k, schedule=base)
+        spec = RoundConfig(n=args.n, k=args.k, kind=base, r=r,
+                           adaptive=adaptive)
         opt = adamw(cosine_schedule(3e-4, args.steps, warmup=20),
                     weight_decay=0.01)
         state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
@@ -102,7 +103,7 @@ def main():
             print(f"model params: {num_params(state.params):,}")
         step = jax.jit(make_straggler_train_step(cfg, opt, spec, delay))
         base_C = spec.to_matrix()
-        scheduler = AdaptiveScheduler(base_C) if adaptive else None
+        scheduler = make_scheduler(spec)
         cluster = None
         losses, vclock, curve = [], 0.0, []
         t0 = time.time()

@@ -40,8 +40,7 @@ from .planner import plan, PlanResult, PLAN_FORMAT_VERSION
 from .completion import (slot_arrival_times, message_arrival_times,
                          message_slot_layout, task_arrival_times,
                          completion_time, lower_bound_time,
-                         first_k_distinct_mask, winner_mask_gather,
-                         simulate_completion, simulate_lower_bound,
+                         first_k_distinct_mask, simulate_completion, simulate_lower_bound,
                          mean_completion_time)
 from .theory import (theorem1_tail_from_H, theorem1_tail_mc, theorem1_mean_mc,
                      lower_bound_tail_mc, lower_bound_mean_mc,
@@ -53,7 +52,8 @@ from .coded import (pc_threshold, pcmm_threshold, pc_encode, pc_decode,
                     pc_worker_compute, pcmm_encode, pcmm_decode,
                     pcmm_worker_compute, simulate_pc_completion,
                     simulate_pcmm_completion)
-from .aggregator import RoundSpec, StragglerAggregator
+from .round import RoundClose, make_round_close, make_scheduler
+from .aggregator import StragglerAggregator
 
 # ------------------- live-layer facade (lazy re-exports) ---------------------
 # repro.live imports from repro.core's submodules, so importing it eagerly

@@ -20,36 +20,35 @@ One SGD iteration = one *round*:
 
      (the n/k factor is folded into the returned gradient).
 
-With ``adaptive=True`` the aggregator re-permutes the base TO matrix's rows
-every round from observed per-worker delay feedback (greedy
-least-covered-first, ``repro.core.scheduling.AdaptiveScheduler``): fetch the
-effective schedule for the coming round with ``current_matrix()`` *before*
-calling ``round_mask`` (it decides which task's data each worker loads).
-``censored_feedback=True`` restricts that feedback to messages that reached
+The round is described by one ``RoundConfig``.  With ``adaptive=True``
+the aggregator re-permutes the base TO matrix's rows every round from
+observed per-worker delay feedback (greedy least-covered-first,
+``repro.core.scheduling.AdaptiveScheduler``): fetch the effective schedule
+for the coming round with ``current_matrix()`` *before* calling
+``round_mask`` (it decides which task's data each worker loads).
+``censored_feedback`` restricts that feedback to messages that reached
 the master before the round completed (what a real master observes), and
-``RoundSpec.messages`` sets the per-round message budget (paper Sec. V-C):
-results become available in per-message lumps instead of per slot.
+``messages`` sets the per-round message budget (paper Sec. V-C): results
+become available in per-message lumps instead of per slot.
 
-Ragged rounds: ``RoundSpec.loads`` gives each TO-matrix row its own load
-(trailing slots ``MASKED``; winner weights there are identically zero and
-eq. (61) normalizes by the realized selected count), and
-``rebalance=True`` (with ``adaptive=True``) additionally re-allocates
-whole slots between workers each round from the same feedback
-(``greedy_load_rebalance`` under the fixed total budget ``sum(loads)``,
-per-worker cap ``r``) — fetch ``current_loads()``/``current_matrix()``
-before each round.  ``RoundSpec.comm_eps`` adds the serialized per-message
-protocol overhead (Ozfatura et al.'s communication/computation trade-off).
+Ragged rounds: ``loads`` gives each TO-matrix row its own load (trailing
+slots ``MASKED``; winner weights there are identically zero and eq. (61)
+normalizes by the realized selected count), and ``rebalance`` additionally
+re-allocates whole slots between workers each round from the same
+feedback (``greedy_load_rebalance`` under the fixed total budget
+``sum(loads)``, per-worker cap ``r``) — fetch ``current_loads()`` /
+``current_matrix()`` before each round.  ``comm_eps`` adds the serialized
+per-message protocol overhead (Ozfatura et al.'s communication/computation
+trade-off).
 
 The selection mask is a deterministic function of the arrival times and is
 computed identically on every shard (cheap: n*r scalars), keeping the whole
-round a single SPMD step.  Task arrivals go through the fused MC engine's
-static gather layout (``task_gather_plan``) rather than the old per-call
-scatter-min, and ``expected_completion`` delegates to the engine's
-``sweep_rounds`` — there is no separate simulation code path left here.
+round a single SPMD step.  The round itself is ``repro.core.round``'s round
+close, and ``expected_completion`` delegates to the engine's
+``sweep_rounds`` — there is no separate simulation code path here.
 """
 from __future__ import annotations
 
-import dataclasses
 import zlib
 from typing import Any, Tuple
 
@@ -57,91 +56,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import montecarlo, scheduling
-from . import spec as spec_mod
+from . import montecarlo
 from .cluster import IIDProcess, as_process
+from .round import make_round_close, make_scheduler
 from .spec import RoundConfig
-from .completion import (apply_row_layout, message_arrival_times,
-                         message_slot_layout, row_layout_is_identity,
-                         winner_mask_gather)
 
-__all__ = ["RoundSpec", "StragglerAggregator"]
+__all__ = ["StragglerAggregator"]
 
 Array = jax.Array
 PyTree = Any
-
-
-@dataclasses.dataclass(frozen=True)
-class RoundSpec:
-    """Static description of one scheduling round.
-
-    ``r`` is the slot-grid width (the uniform load, or — with ``loads`` —
-    the per-worker load cap).  ``loads`` makes the round ragged: row ``i``
-    of the TO matrix keeps only its first ``loads[i]`` slots (for adaptive
-    load re-balancing, ``loads`` is the *initial budget* under the cap
-    ``r``).  ``comm_eps`` is the serialized per-message protocol overhead
-    (Ozfatura et al.'s communication/computation trade-off).
-
-    ``deadline`` caps the round's wall-clock (fault tolerance — under
-    fault-injecting delay processes a round may otherwise never reach k
-    results); ``deadline_policy`` picks the fallback: ``"wait"`` (report
-    the true completion, flag the miss), ``"close_partial"`` (close at the
-    deadline with whatever arrived — eq. 61 renormalizes by the realized
-    count), or ``"reissue"`` (close partial + the adaptive scheduler
-    re-gathers undelivered tasks first next round).
-    """
-    n: int            # number of logical tasks == number of workers
-    r: int            # computation load (tasks per worker) / grid width
-    k: int            # computation target (distinct results needed)
-    schedule: str = "ss"   # cs | ss | ra | block
-    seed: int = 0          # for RA matrices
-    messages: int | None = None  # per-round messages per worker
-                                 # (None = one per slot, eq. 1)
-    loads: tuple | None = None   # per-row loads (ragged rounds)
-    comm_eps: float = 0.0        # per-message protocol overhead
-    deadline: float | None = None      # per-round wall-clock cap
-    deadline_policy: str = "wait"      # wait | close_partial | reissue
-
-    def __post_init__(self):
-        spec_mod._legacy_warning(
-            "RoundSpec", "call .to_round_spec() (field map: schedule→kind; "
-            "adaptive / censored_feedback / rebalance / dead_after now live "
-            "on RoundConfig)")
-        if self.loads is not None:
-            object.__setattr__(self, "loads",
-                               tuple(int(v) for v in self.loads))
-        # one canonical validator (repro.core.spec.RoundConfig) — a bare
-        # RoundSpec carries no adaptivity, so only the schedule-shape checks
-        # apply (``reissue`` stands alone here: its adaptive requirement is
-        # enforced where the scheduler is built, as before).
-        RoundConfig(n=self.n, k=self.k, kind=self.schedule, r=self.r,
-                    loads=self.loads, messages=self.messages,
-                    comm_eps=self.comm_eps, deadline=self.deadline,
-                    deadline_policy=self.deadline_policy,
-                    adaptive=self.deadline_policy == "reissue",
-                    seed=self.seed)
-
-    @property
-    def n_messages(self) -> int:
-        return self.r if self.messages is None else int(self.messages)
-
-    @property
-    def load_vector(self) -> np.ndarray:
-        return (np.full(self.n, self.r, np.int64) if self.loads is None
-                else np.asarray(self.loads, np.int64))
-
-    def base_matrix(self) -> np.ndarray:
-        """The dense (un-masked) schedule at the grid width ``r`` — the
-        load-rebalancing cap grid."""
-        return scheduling.to_matrix(self.schedule, self.n, self.r,
-                                    **({"seed": self.seed}
-                                       if self.schedule == "ra" else {}))
-
-    def to_matrix(self) -> np.ndarray:
-        kw = {"seed": self.seed} if self.schedule == "ra" else {}
-        if self.loads is not None:
-            kw["loads"] = self.loads
-        return scheduling.to_matrix(self.schedule, self.n, self.r, **kw)
 
 
 def _seed_of(key) -> int:
@@ -167,7 +90,7 @@ class StragglerAggregator:
 
     Usage inside a train loop::
 
-        agg = StragglerAggregator(RoundSpec(n=16, r=2, k=12, schedule="ss"),
+        agg = StragglerAggregator(RoundConfig(n=16, k=12, kind="ss", r=2),
                                   ec2_cluster(16, persistence=0.95))
         for step in range(steps):
             C = agg.current_matrix()                 # schedule this round
@@ -180,118 +103,29 @@ class StragglerAggregator:
     within the micro-batch).
     """
 
-    def __init__(self, spec: RoundSpec, delay, *, adaptive: bool = False,
-                 init_key: Array | None = None, feedback_beta: float = 0.7,
-                 coverage_gamma: float = 0.5,
-                 censored_feedback: bool = False,
-                 rebalance: bool = False,
-                 dead_after: int | None = None):
-        # the adaptive-family cross-field rules live in the one canonical
-        # validator: re-validate the spec WITH the adaptivity flags attached.
-        RoundConfig(n=spec.n, k=spec.k, kind=spec.schedule, r=spec.r,
-                    loads=spec.loads, messages=spec.messages,
-                    comm_eps=spec.comm_eps, deadline=spec.deadline,
-                    deadline_policy=spec.deadline_policy,
-                    adaptive=adaptive, rebalance=rebalance,
-                    censored_feedback=censored_feedback,
-                    dead_after=dead_after, feedback_beta=feedback_beta,
-                    coverage_gamma=coverage_gamma, seed=spec.seed)
-        self.spec = spec
+    def __init__(self, cfg: RoundConfig, delay, *,
+                 init_key: Array | None = None):
+        self.cfg = cfg
         self.process = as_process(delay)
-        self.rebalance = bool(rebalance)
-        # rebalance masks slots dynamically, so its base is the dense cap
-        # grid; otherwise the (possibly ragged) schedule bakes its masks in.
-        self.base_C = spec.base_matrix() if rebalance else spec.to_matrix()
-        if rebalance and sorted(self.base_C[:, 0].tolist()) != list(
-                range(spec.n)):
-            # e.g. a dense RA base: without a slot-0 diagonal a shed load
-            # can leave tasks with no active copy (t_done = +inf)
-            raise ValueError("rebalance needs a slot-0-diagonal base "
-                             "schedule (cs / ss) so every task stays "
-                             "covered under any load vector")
-        self._plan = montecarlo.task_gather_plan(self.base_C, spec.n)
-        if adaptive:
-            kw = dict(beta=feedback_beta, gamma=coverage_gamma)
-            if dead_after is not None:
-                kw.update(dead_after=int(dead_after), target_k=spec.k)
-            if rebalance:
-                self.scheduler = scheduling.AdaptiveScheduler(
-                    self.base_C, loads=spec.loads, rebalance=True, **kw)
-            else:
-                self.scheduler = scheduling.AdaptiveScheduler(self.base_C,
-                                                              **kw)
-        else:
-            self.scheduler = None
-        self.censored = bool(censored_feedback)
-        if rebalance:
-            # re-balanced loads are decided per round, so the message
-            # grouping cannot be a static row layout (the dense base
-            # would bake a full-load grouping in); the round function
-            # gathers the load-indexed closing-slot table instead.
-            self._row_layout = None
-            self._rb_remap = montecarlo._rebalance_remap_table(
-                spec.r, spec.n_messages)
-        else:
-            # static per-row message layout (closing-slot remap + overhead
-            # offsets + ragged masks); None when it is the identity
-            layout = message_slot_layout(
-                scheduling.loads_of_matrix(self.base_C), spec.r,
-                spec.n_messages, spec.comm_eps)
-            self._row_layout = (None if row_layout_is_identity(layout)
-                                else layout)
-            self._rb_remap = None
+        self.scheduler = make_scheduler(cfg)
+        self._close = make_round_close(cfg)
         if init_key is None:
-            init_key = jax.random.PRNGKey(spec.seed)
+            init_key = jax.random.PRNGKey(cfg.seed)
         # trial id 0: a live training run is the single realization of a
         # trace-backed process (lane 0 of its table); parametric processes
         # ignore the id.
         self._state = self.process.init_trials(
-            init_key[None], jnp.zeros((1,), jnp.int32), spec.n)
+            init_key[None], jnp.zeros((1,), jnp.int32), cfg.n)
         self._rounds_done = 0
-        # a deadline that actually closes the round (close_partial /
-        # reissue) caps the winner selection; "wait" keeps the true
-        # completion and only *flags* misses.
-        self._dl_close = (spec.deadline
-                          if spec.deadline is not None
-                          and spec.deadline_policy != "wait" else None)
         self.rounds_missed = 0          # rounds that blew the deadline
         self.realized_k_history: list[float] = []   # realized count / round
         self._round = jax.jit(self._round_fn)
 
-    # --- one round, jitted: delays + winner weights in base-row space ------
+    # --- one round, jitted: the cluster's delays, then the round close -----
     def _round_fn(self, state, keys, row_of_worker, loads_w):
-        n, r, k = self.spec.n, self.spec.r, self.spec.k
-        state, T1, T2 = self.process.step(state, keys, n, r)
-        # raw per-slot availability (eq. 1), permuted to base-row space;
-        # the message grouping is applied per ROW (a worker's grouping
-        # follows the row it executes), so remap after the permutation —
-        # for uniform loads the remap is row-invariant and this is
-        # bit-identical to remapping before it.
-        s = message_arrival_times(T1, T2, r)[0]          # identity: eq. (1)
-        worker_of_row = jnp.argsort(row_of_worker)       # inverse permutation
-        s2 = s[worker_of_row]                            # row-major arrivals
-        if self._row_layout is not None:
-            s2 = apply_row_layout(s2, self._row_layout)
-        if self.rebalance:
-            # row p inherits its executor's re-balanced load this round
-            l_row = loads_w[worker_of_row]
-            s2 = jnp.where(jnp.arange(r)[None, :] < l_row[:, None], s2,
-                           jnp.inf)
-            if self._rb_remap is not None:
-                # message budget under dynamic loads: gather each row's
-                # load-indexed closing-slot remap (same table the MC
-                # engine's rounds scan uses)
-                mm = jnp.take(jnp.asarray(self._rb_remap), l_row - 1, axis=0)
-                s2 = jnp.take_along_axis(s2, mm, axis=-1)
-        w2, t_done = winner_mask_gather(self.base_C, self._plan, s2, n, k,
-                                        deadline=self._dl_close)
-        # per-task delivery by the (capped) round close — the reissue
-        # policy's re-gather signal
-        tau = montecarlo.task_arrival_times_gather(self._plan, s2)
-        delivered = (tau <= t_done) & jnp.isfinite(tau)
-        weights = w2[row_of_worker]                      # back to worker-major
-        arr_w = s2[row_of_worker]                        # worker-major arrivals
-        return state, T1[0], arr_w, weights, t_done, delivered
+        state, T1, T2 = self.process.step(state, keys, self.cfg.n,
+                                          self.cfg.width)
+        return state, T1[0], self._close(T1, T2, row_of_worker, loads_w)
 
     def current_matrix(self) -> np.ndarray:
         """The effective TO matrix for the coming round (row ``w`` = tasks
@@ -299,14 +133,14 @@ class StragglerAggregator:
         Static schedules return the base matrix; adaptive ones the
         feedback-driven row re-assignment (and load re-balance)."""
         if self.scheduler is None:
-            return self.base_C
+            return self.cfg.to_matrix()
         return self.scheduler.matrix()
 
     def current_loads(self) -> np.ndarray:
         """Per-worker loads for the coming round (matches
         ``current_matrix()``'s active slots)."""
         if self.scheduler is None:
-            return self.spec.load_vector
+            return self.cfg.load_vector
         return self.scheduler.loads()
 
     def round_mask(self, key: Array) -> Tuple[Array, Array]:
@@ -319,32 +153,29 @@ class StragglerAggregator:
         # the live loop learns it ran off the recording's end *before* the
         # round executes, with the remedy in the error message.
         self.process.check_rounds(self._rounds_done + 1)
-        row_of_worker = (np.arange(self.spec.n) if self.scheduler is None
+        cfg = self.cfg
+        row_of_worker = (np.arange(cfg.n) if self.scheduler is None
                          else self.scheduler.row_of_worker())
-        loads_w = (self.scheduler.loads() if self.rebalance
-                   else self.spec.load_vector)
-        self._state, t1, arrivals, weights, t_done, delivered = self._round(
+        loads_w = (self.scheduler.loads() if cfg.rebalance
+                   else cfg.load_vector)
+        self._state, t1, rc = self._round(
             self._state, key[None], jnp.asarray(row_of_worker),
             jnp.asarray(loads_w))
         self._rounds_done += 1
-        realized = float(np.asarray(weights).sum())
-        self.realized_k_history.append(realized)
-        if self.spec.deadline is not None:
-            blown = (float(t_done) > self.spec.deadline
-                     if self._dl_close is None else realized < self.spec.k)
-            self.rounds_missed += int(blown)
+        self.realized_k_history.append(float(np.asarray(rc.weights).sum()))
+        self.rounds_missed += int(rc.missed)
         if self.scheduler is not None:
-            if self.censored:
-                # a real master only sees messages that beat the deadline
+            if cfg.censored_feedback:
+                # a real master only sees messages that beat the round close
                 self.scheduler.observe(np.asarray(t1),
-                                       arrivals=np.asarray(arrivals),
-                                       t_done=float(t_done))
+                                       arrivals=np.asarray(rc.arr_w),
+                                       t_done=float(rc.t_eff))
             else:
                 self.scheduler.observe(np.asarray(t1))
-            if self.spec.deadline_policy == "reissue":
+            if cfg.deadline_policy == "reissue":
                 # undelivered tasks get re-gather priority next round
-                self.scheduler.set_need(~np.asarray(delivered))
-        return weights, t_done
+                self.scheduler.set_need(~np.asarray(rc.delivered))
+        return rc.weights, rc.t_eff
 
     def combine(self, slot_grads: PyTree, weights: Array) -> PyTree:
         """eq. (61): grad = (n/k) * mean over selected tasks of task grads
@@ -381,25 +212,8 @@ class StragglerAggregator:
                 # recorded horizon after the replay offset
                 rounds = min(rounds, self.process.trace.rounds
                              - int(self.process.start_round))
-        m = self.spec.messages
-        if self.rebalance:
-            spec = montecarlo.adaptive_spec("s", self.base_C, messages=m,
-                                            loads=self.spec.loads,
-                                            rebalance=True)
-        elif self.scheduler is not None:
-            spec = montecarlo.adaptive_spec("s", self.base_C, messages=m)
-        else:
-            spec = montecarlo.to_spec("s", self.base_C, messages=m,
-                                      comm_eps=self.spec.comm_eps)
-        kw = {}
-        if self.scheduler is not None:   # estimate the policy actually run
-            kw = dict(feedback_beta=self.scheduler.beta,
-                      coverage_gamma=self.scheduler.gamma,
-                      censored_feedback=self.censored)
-        if self.spec.deadline is not None:
-            kw.update(deadline=self.spec.deadline,
-                      deadline_policy=self.spec.deadline_policy)
         res = montecarlo.sweep_rounds(
-            [spec], self.process, self.spec.n, rounds=rounds, k=self.spec.k,
-            trials=trials, seed=_seed_of(key), **kw)
+            [self.cfg.to_scheme_spec("s")], self.process, self.cfg.n,
+            rounds=rounds, trials=trials, seed=_seed_of(key),
+            **self.cfg.sweep_rounds_kwargs())
         return res.mean_round("s")

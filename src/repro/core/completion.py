@@ -38,7 +38,7 @@ __all__ = [
     "slot_arrival_times", "message_arrival_times", "message_slot_layout",
     "row_layout_is_identity", "apply_row_layout", "task_arrival_times",
     "completion_time", "lower_bound_time", "first_k_distinct_mask",
-    "winner_mask_gather", "simulate_completion", "simulate_lower_bound",
+    "simulate_completion", "simulate_lower_bound",
     "mean_completion_time",
 ]
 
@@ -57,7 +57,7 @@ def message_slot_layout(loads, r: int, messages: int,
     returns ``(smap, offsets, active)`` — the (n, r) closing-slot remap,
     per-slot overhead offsets (None when ``comm_eps`` is 0) and active-slot
     mask (None when dense) — shared by ``message_arrival_times`` and the
-    aggregator's row-major arrival path."""
+    round close's row-major arrival path."""
     lv = np.asarray(loads, np.int64)
     n = lv.shape[0]
     smap = np.broadcast_to(np.arange(r), (n, r)).copy()
@@ -87,8 +87,7 @@ def apply_row_layout(s: Array, layout) -> Array:
     """Apply a static per-row message layout (``message_slot_layout``) to
     per-slot arrivals ``s`` (..., n, r): closing-slot remap, overhead
     offsets, +inf beyond each row's load.  The single implementation
-    shared by ``message_arrival_times``, the aggregator, and the train
-    step."""
+    shared by ``message_arrival_times`` and the round close."""
     smap, off, act = layout
     out = jnp.take_along_axis(
         s, jnp.broadcast_to(jnp.asarray(smap), s.shape), axis=-1)
@@ -193,31 +192,19 @@ def first_k_distinct_mask(C: Array, s: Array, n: int, k: int, *,
     """
     active = _static_active(C)             # static, before any jnp tracing
     tau = task_arrival_times(C, s, n)                    # (..., n)
-    return _winner_weights(jnp.asarray(C), s, tau, k, active,
-                           deadline=deadline)
-
-
-def winner_mask_gather(C: Array, plan: np.ndarray, s: Array, n: int, k: int,
-                       *, deadline: float | None = None
-                       ) -> Tuple[Array, Array]:
-    """``first_k_distinct_mask`` with task arrivals computed through the
-    fused engine's static gather layout (``task_gather_plan(C, n)``) instead
-    of a dynamic scatter-min — the TPU-friendly form used by the round API
-    (aggregator / train step hot paths)."""
-    active = _static_active(C)             # static, before any jnp tracing
-    tau = montecarlo.task_arrival_times_gather(plan, s)  # (..., n)
-    return _winner_weights(jnp.asarray(C), s, tau, k, active,
-                           deadline=deadline)
-
-
-def _winner_weights(C: Array, s: Array, tau: Array, k: int,
-                    active: np.ndarray | None, *,
-                    deadline: float | None = None) -> Tuple[Array, Array]:
     t_done = completion_time(tau, k)                     # (...,)
     if deadline is not None:
         # close the round at the deadline with whatever has arrived —
         # t_done stays finite even when fewer than k tasks ever arrive
         t_done = jnp.minimum(t_done, jnp.asarray(deadline, tau.dtype))
+    return _winner_weights(jnp.asarray(C), s, tau, t_done, active), t_done
+
+
+def _winner_weights(C: Array, s: Array, tau: Array, t_done: Array,
+                    active: np.ndarray | None) -> Array:
+    """Per-slot eq.-61 weights of a round closed at ``t_done``: the
+    earliest copy of every task delivered by then (shared with the round
+    close, ``repro.core.round``)."""
     # +inf-safe: a censored task (tau = +inf, fault-killed worker) must
     # not be "selected" when t_done is itself +inf (inf <= inf is True)
     selected = (tau <= t_done[..., None]) & jnp.isfinite(tau)
@@ -234,8 +221,7 @@ def _winner_weights(C: Array, s: Array, tau: Array, k: int,
     per_task_count = jnp.zeros_like(tau).at[..., C.reshape(-1)].add(
         ones.reshape(ones.shape[:-2] + (-1,)))
     cnt_at_slot = jnp.maximum(per_task_count[..., C], 1.0)
-    weights = ones / cnt_at_slot
-    return weights, t_done
+    return ones / cnt_at_slot
 
 
 # ---------------- Monte-Carlo drivers ----------------------------------------

@@ -49,7 +49,6 @@ from .montecarlo import (SchemeSpec, lb_spec, pc_spec, pcmm_spec, sweep_rounds,
                          to_spec)
 from .scheduling import (cyclic_to_matrix, random_assignment_to_matrix,
                          staircase_to_matrix)
-from .spec import _internal
 
 __all__ = ["GridCell", "GridSpec", "GridResult", "stream_grid",
            "GRID_FORMAT_VERSION", "FAMILIES"]
@@ -437,12 +436,8 @@ def stream_grid(cells: Sequence[GridCell], *, devices=None,
         # spec names are only unique per cell — prefix with the cell name
         # (outside the compiled program: outputs are group-keyed, so the
         # renames never retrace).
-        fused = []
-        with _internal():
-            for cell in grp:
-                for sp in cell.specs:
-                    fused.append(dataclasses.replace(
-                        sp, name=f"{cell.name}:{sp.name}"))
+        fused = [dataclasses.replace(sp, name=f"{cell.name}:{sp.name}")
+                 for cell in grp for sp in cell.specs]
         sig, _, _ = mc._eval_layout(tuple(fused), c0.n, c0.r_max, c0.ks)
         sigs.add(sig)
         while len(pending) >= pipeline:       # keep the window bounded

@@ -133,8 +133,7 @@ import numpy as np
 
 from .. import obs
 from ..sharding import shard_trials, trial_devices
-from .spec import (DEADLINE_POLICIES, _internal, _legacy_warning,
-                   validate_deadline)
+from .spec import DEADLINE_POLICIES, validate_deadline
 
 __all__ = [
     "SchemeSpec", "SweepResult", "RoundsResult", "to_spec", "lb_spec",
@@ -169,16 +168,6 @@ class SchemeSpec:
     comm_eps: float = 0.0           # per-message protocol overhead: a
                                     # worker's l-th message lands (l+1)*eps
                                     # late (serialized uplink)
-
-    def __post_init__(self):
-        # no validation here — invalid specs are (and stay) rejected at
-        # sweep time by ``_check_specs`` with engine-level context; direct
-        # construction is merely deprecated in favor of the factories /
-        # ``RoundConfig.to_scheme_spec()``.
-        _legacy_warning(
-            "SchemeSpec", "call .to_scheme_spec() (or use the to_spec / "
-            "tau_spec / adaptive_spec / lb_spec / pc_spec / pcmm_spec "
-            "factories)")
 
     @property
     def load(self) -> int:
@@ -246,18 +235,16 @@ def to_spec(name: str, C, messages: Optional[int] = None, *,
     equivalently encoded as trailing -1 sentinels in ``C``); ``comm_eps``
     is the per-message protocol overhead."""
     Cf, lt = _freeze_ragged(C, loads)
-    with _internal():
-        return SchemeSpec(name=name, kind="to", C=Cf, messages=messages,
-                          loads=lt, comm_eps=float(comm_eps))
+    return SchemeSpec(name=name, kind="to", C=Cf, messages=messages,
+                      loads=lt, comm_eps=float(comm_eps))
 
 
 def tau_spec(name: str, C, messages: Optional[int] = None, *,
              loads=None, comm_eps: float = 0.0) -> SchemeSpec:
     """Raw task-arrival samples for a TO matrix (no order statistics)."""
     Cf, lt = _freeze_ragged(C, loads)
-    with _internal():
-        return SchemeSpec(name=name, kind="tau", C=Cf, messages=messages,
-                          loads=lt, comm_eps=float(comm_eps))
+    return SchemeSpec(name=name, kind="tau", C=Cf, messages=messages,
+                      loads=lt, comm_eps=float(comm_eps))
 
 
 def adaptive_spec(name: str, C, messages: Optional[int] = None, *,
@@ -274,14 +261,12 @@ def adaptive_spec(name: str, C, messages: Optional[int] = None, *,
         # the budget stays a budget — do NOT fold it into row masks
         lt = (None if loads is None
               else tuple(int(v) for v in np.asarray(loads, np.int64)))
-        with _internal():
-            return SchemeSpec(name=name, kind="adaptive",
-                              C=_freeze_matrix(C), messages=messages,
-                              loads=lt, rebalance=True)
+        return SchemeSpec(name=name, kind="adaptive",
+                          C=_freeze_matrix(C), messages=messages,
+                          loads=lt, rebalance=True)
     Cf, lt = _freeze_ragged(C, loads)
-    with _internal():
-        return SchemeSpec(name=name, kind="adaptive", C=Cf,
-                          messages=messages, loads=lt)
+    return SchemeSpec(name=name, kind="adaptive", C=Cf,
+                      messages=messages, loads=lt)
 
 
 def lb_spec(r: Optional[int] = None, name: str = "lb",
@@ -305,17 +290,15 @@ def lb_spec(r: Optional[int] = None, name: str = "lb",
             lt = tuple(int(v) for v in lv)
     elif r is None:
         raise ValueError("need a load r (or a loads vector)")
-    with _internal():
-        return SchemeSpec(name=name, kind="lb", r=int(r), messages=messages,
-                          loads=lt, comm_eps=float(comm_eps))
+    return SchemeSpec(name=name, kind="lb", r=int(r), messages=messages,
+                      loads=lt, comm_eps=float(comm_eps))
 
 
 def pc_spec(r: int, name: str = "pc") -> SchemeSpec:
     """Polynomially-coded scheme at load ``r`` — one-shot by construction
     (the PC decoder needs a worker's full sum, eqs. 51-52); use ``pcmm_spec``
     for coded rounds with an intra-round message budget."""
-    with _internal():
-        return SchemeSpec(name=name, kind="pc", r=int(r))
+    return SchemeSpec(name=name, kind="pc", r=int(r))
 
 
 def pcmm_spec(r: int, name: str = "pcmm",
@@ -323,9 +306,7 @@ def pcmm_spec(r: int, name: str = "pcmm",
     """Polynomially-coded multi-message scheme at load ``r``; ``messages``
     bundles its per-slot partials into fewer messages (eqs. 56-57 keep
     counting partials, they just arrive in lumps)."""
-    with _internal():
-        return SchemeSpec(name=name, kind="pcmm", r=int(r),
-                          messages=messages)
+    return SchemeSpec(name=name, kind="pcmm", r=int(r), messages=messages)
 
 
 def _pc_threshold(n: int, r: int) -> int:
@@ -408,8 +389,8 @@ def _rebalance_remap(spec: SchemeSpec) -> Optional[np.ndarray]:
 
 def _rebalance_remap_table(cap: int, messages: int) -> Optional[np.ndarray]:
     """The ``(cap, cap)`` load-indexed closing-slot table itself (see
-    ``_rebalance_remap``); shared with the live aggregator, whose round
-    function applies the same gather to its single realization."""
+    ``_rebalance_remap``); shared with the round close
+    (``repro.core.round``), which applies the same gather to one round."""
     if messages >= cap:
         return None
     tab = np.empty((cap, cap), np.int64)
@@ -1829,6 +1810,7 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
     bit-identical to the pre-deadline engine.
     """
     from . import scheduling                    # adaptive assignment
+    from .round import policy_close
 
     static_specs = tuple(sp for sp in specs if sp.kind != "adaptive")
     ad_specs = tuple(sp for sp in specs if sp.kind == "adaptive")
@@ -1844,14 +1826,6 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
     kf = np.float32(ks)
     nf = np.float32(n)
 
-    def _policy_close(v, by, dv):
-        """Apply the fallback policy to one scheme's raw completion ``v``
-        (chunk,) given its arrival counts: returns (v_eff, realized,
-        missed)."""
-        if policy == "wait":
-            return v, jnp.minimum(dv, kf), (~(v <= DL)).astype(jnp.float32)
-        return (jnp.minimum(v, DL), jnp.minimum(by, kf),
-                (by < kf).astype(jnp.float32))
     ad_mats = tuple(sp.matrix() for sp in ad_specs)
     # rebalance specs mask slots dynamically, so their plan must keep every
     # slot of the dense base (an identity plan — a static slot map would
@@ -1965,7 +1939,8 @@ def _build_rounds_fn(specs: Tuple[SchemeSpec, ...], process, n: int,
         Returns (v_eff, aux | None); updates ``new_backs`` under reissue."""
         if DL is None:
             return v, None
-        v_eff, realized, missed = _policy_close(v, by, dv)
+        v_eff, realized, missed = policy_close(v, by, dv, k=kf, deadline=DL,
+                                               policy=policy)
         if reissue:
             nb = jnp.clip(backs[nm] + kf - jnp.minimum(by, kf), 0.0, nf)
             new_backs[nm] = nb

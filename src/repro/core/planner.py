@@ -54,7 +54,7 @@ from . import montecarlo as mc
 from . import theory
 from .. import obs
 from .grid import GridSpec, _cell_name, _family_spec
-from .spec import RoundConfig, _internal
+from .spec import RoundConfig
 
 __all__ = ["plan", "PlanResult", "PLAN_FORMAT_VERSION"]
 
@@ -196,9 +196,8 @@ def _enumerate_points(gs: GridSpec, k_default: int):
                         else:
                             k_eff = k if k is not None else k_default
                         if sname not in specs:
-                            with _internal():
-                                specs[sname] = dataclasses.replace(
-                                    sp, name=sname)
+                            specs[sname] = dataclasses.replace(
+                                sp, name=sname)
                         points.append(_Point(
                             name=cname, spec_name=sname, family=fam, r=r,
                             messages=m, comm_eps=eps, k=int(k_eff),

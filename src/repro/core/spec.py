@@ -1,37 +1,22 @@
 """The canonical round configuration — one frozen ``RoundConfig`` + one
 validator shared by the simulator, the trainer, and the live execution layer.
 
-Historically the same scheme/load/messages/deadline fields were re-declared
-three times with drifting validation: ``SchemeSpec`` (the MC engine's
-per-scheme record, validated at sweep time), ``RoundSpec.__post_init__``
-(the aggregator), and ad-hoc checks in the launcher CLI.  ``RoundConfig``
-subsumes all three:
-
 * ``RoundConfig(...)`` runs the one canonical validator (k/r ranges,
   message budgets, ragged-load coverage, deadline/policy pairing, and the
-  adaptive-family cross-field rules that used to live in
-  ``StragglerAggregator.__init__``);
-* ``.to_round_spec()`` / ``.to_scheme_spec()`` derive the legacy objects
-  (bit-exact under common random numbers — they are the same matrices and
-  budgets, just re-packaged);
-* ``.sweep_rounds_kwargs()`` / ``.aggregator_kwargs()`` feed the MC engine
-  and the trainer;
+  adaptive-family cross-field rules);
+* ``.to_scheme_spec()`` derives the MC engine's per-scheme record, and
+  ``.sweep_rounds_kwargs()`` the engine's round arguments;
+  ``repro.core.round`` builds the round close and the adaptive scheduler
+  that the aggregator, the train step and the live master run from it;
 * ``to_json`` / ``from_json`` / ``save`` / ``load`` round-trip the config
   through a versioned JSON document (``python -m repro.launch.train
   --config round.json`` and the live layer's master/worker handshake both
   ship this form).
-
-``SchemeSpec(...)`` and ``RoundSpec(...)`` remain constructible but emit a
-single ``DeprecationWarning`` per process pointing at the new spelling;
-every internal call site builds them through ``RoundConfig`` (or the
-factory helpers), which suppresses the warning via ``_internal()``.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
-import warnings
 from pathlib import Path
 from typing import Optional
 
@@ -50,44 +35,6 @@ DEADLINE_POLICIES = ("wait", "close_partial", "reissue")
 
 CONFIG_FORMAT = "repro.round_config"
 CONFIG_VERSION = 1
-
-
-# ------------------------- deprecation machinery -----------------------------
-#
-# Legacy constructors (SchemeSpec / RoundSpec) warn exactly once per class
-# per process — but never when the construction comes from inside this
-# package (the factories, RoundConfig conversions, and the engine itself
-# build them constantly).
-
-_INTERNAL = 0
-_warned: set = set()
-
-
-@contextlib.contextmanager
-def _internal():
-    """Mark the enclosed legacy-object constructions as internal (no
-    deprecation warning)."""
-    global _INTERNAL
-    _INTERNAL += 1
-    try:
-        yield
-    finally:
-        _INTERNAL -= 1
-
-
-def _legacy_warning(cls_name: str, hint: str) -> None:
-    if _INTERNAL or cls_name in _warned:
-        return
-    _warned.add(cls_name)
-    warnings.warn(
-        f"constructing {cls_name}(...) directly is deprecated: build a "
-        f"repro.core.RoundConfig(...) and {hint}",
-        DeprecationWarning, stacklevel=4)
-
-
-def _reset_legacy_warnings() -> None:
-    """Re-arm the once-per-process deprecation warnings (test helper)."""
-    _warned.clear()
 
 
 # --------------------------- shared validators -------------------------------
@@ -199,8 +146,7 @@ class RoundConfig:
         if not 0.0 <= self.coverage_gamma <= 1.0:
             raise ValueError(f"coverage_gamma must be in [0, 1], got "
                              f"{self.coverage_gamma}")
-        # adaptive-family cross-field rules (formerly scattered across
-        # StragglerAggregator.__init__ and the launcher CLI).
+        # adaptive-family cross-field rules
         if self.censored_feedback and not self.adaptive:
             raise ValueError("censored_feedback requires adaptive=True — "
                              "static schedules take no feedback to censor")
@@ -276,33 +222,21 @@ class RoundConfig:
             kw["loads"] = self.loads
         return scheduling.to_matrix(self.kind, self.n, self.width, **kw)
 
-    # -------------------------- legacy derivations ---------------------------
-
-    def to_round_spec(self):
-        """The equivalent (legacy) ``repro.core.aggregator.RoundSpec`` —
-        bit-exact: same matrices, budgets, and deadline semantics."""
-        from .aggregator import RoundSpec
-        with _internal():
-            return RoundSpec(n=self.n, r=self.width, k=self.k,
-                             schedule=self.kind, seed=self.seed,
-                             messages=self.messages, loads=self.loads,
-                             comm_eps=self.comm_eps, deadline=self.deadline,
-                             deadline_policy=self.deadline_policy)
+    # ---------------------------- engine forms -------------------------------
 
     def to_scheme_spec(self, name: Optional[str] = None):
-        """The equivalent (legacy) ``repro.core.montecarlo.SchemeSpec`` for
-        the MC engine — adaptive configs map to ``adaptive_spec`` (base
-        matrix + feedback re-planning), static ones to ``to_spec``."""
+        """The MC engine's ``repro.core.montecarlo.SchemeSpec`` for this
+        round — adaptive configs map to ``adaptive_spec`` (base matrix +
+        feedback re-planning), static ones to ``to_spec``."""
         from . import montecarlo
         nm = self.kind if name is None else name
-        with _internal():
-            if self.adaptive:
-                return montecarlo.adaptive_spec(
-                    nm, self.base_matrix(), messages=self.messages,
-                    loads=self.loads, rebalance=self.rebalance)
-            return montecarlo.to_spec(
+        if self.adaptive:
+            return montecarlo.adaptive_spec(
                 nm, self.base_matrix(), messages=self.messages,
-                loads=self.loads, comm_eps=self.comm_eps)
+                loads=self.loads, rebalance=self.rebalance)
+        return montecarlo.to_spec(
+            nm, self.base_matrix(), messages=self.messages,
+            loads=self.loads, comm_eps=self.comm_eps)
 
     def sweep_rounds_kwargs(self) -> dict:
         """Keyword arguments for ``montecarlo.sweep_rounds`` /
@@ -314,16 +248,6 @@ class RoundConfig:
             kw.update(deadline=self.deadline,
                       deadline_policy=self.deadline_policy)
         return kw
-
-    def aggregator_kwargs(self) -> dict:
-        """Keyword arguments for ``StragglerAggregator(spec, process,
-        **kwargs)`` matching this config's adaptivity."""
-        return dict(adaptive=self.adaptive,
-                    feedback_beta=self.feedback_beta,
-                    coverage_gamma=self.coverage_gamma,
-                    censored_feedback=self.censored_feedback,
-                    rebalance=self.rebalance,
-                    dead_after=self.dead_after)
 
     # ------------------------------ JSON form --------------------------------
 

@@ -129,7 +129,7 @@ def build_train(cfg, shape: str, ctx: MeshCtx, *, r: int, k_frac: float,
                 schedule: str, zero1: bool = False):
     n = ctx.data_size
     k = max(1, int(round(k_frac * n)))
-    spec = RoundConfig(n=n, k=k, kind=schedule, r=r).to_round_spec()
+    spec = RoundConfig(n=n, k=k, kind=schedule, r=r)
     opt = adamw(1e-4)
     step = make_straggler_train_step(cfg, opt, spec, scenario1(),
                                      scan_slots=False)

@@ -31,7 +31,7 @@ from ..configs import ARCH_IDS, get_config
 from ..core import (AR1Process, AdaptiveScheduler, BimodalStragglerDelays,
                     DelayTrace, FAULT_SCENARIOS, RoundConfig, TraceProcess,
                     ec2_cluster, heterogeneous_scales, load_trace,
-                    make_scenario, save_trace, scenario1)
+                    make_scenario, make_scheduler, save_trace, scenario1)
 from ..data import TaskPartition, lm_task_batches
 from ..models import num_params
 from ..optim import adamw, cosine_schedule
@@ -100,7 +100,7 @@ def build_cluster(args, seeds):
 @dataclasses.dataclass
 class TrainRun:
     """What ``build_run`` sets up for the training loop."""
-    spec: object                # the round's RoundSpec
+    spec: RoundConfig           # the round
     part: TaskPartition
     state: object               # TrainState, resumed or fresh
     start: int                  # first step to run
@@ -251,8 +251,12 @@ def build_run(args, cfg, seeds, rc: RoundConfig) -> TrainRun:
     task partition, AdamW on the cosine schedule, the train state (resumed
     from the newest checkpoint under ``--resume``), the delay source, the
     jitted straggler step and, with ``--adaptive``, the scheduler.  Call
-    it inside the run's mesh context."""
-    spec = rc.to_round_spec()
+    it inside the run's mesh context.  Raises ``ValueError`` on a round
+    the train loop does not run: its feedback is the uncensored per-worker
+    compute mean, and its slot batches cannot follow a re-balance."""
+    if rc.censored_feedback or rc.rebalance:
+        raise ValueError("the train loop runs neither censored_feedback nor "
+                         "rebalance; drop them from the round config")
     delay = build_cluster(args, seeds)
     part = TaskPartition(n=args.n, global_batch=args.batch,
                          seq_len=args.seq, vocab=cfg.vocab_size,
@@ -273,13 +277,9 @@ def build_run(args, cfg, seeds, rc: RoundConfig) -> TrainRun:
     if hasattr(delay, "check_rounds"):
         # fail fast (with the remedy) instead of r rounds into the run
         delay.check_rounds(args.steps - start)
-    step_fn = jax.jit(make_straggler_train_step(cfg, opt, spec, delay))
-    base_C = spec.to_matrix()
-    sched_kw = ({} if args.dead_after is None
-                else {"dead_after": args.dead_after, "target_k": spec.k})
-    sched = (AdaptiveScheduler(base_C, **sched_kw)
-             if args.adaptive else None)
-    return TrainRun(spec, part, state, start, step_fn, base_C, sched)
+    step_fn = jax.jit(make_straggler_train_step(cfg, opt, rc, delay))
+    return TrainRun(rc, part, state, start, step_fn, rc.to_matrix(),
+                    make_scheduler(rc))
 
 
 def main(argv=None):
@@ -317,10 +317,13 @@ def main(argv=None):
     loads = rc.loads
 
     with mesh_context(ctx):
-        run = build_run(args, cfg, seeds, rc)
+        try:
+            run = build_run(args, cfg, seeds, rc)
+        except ValueError as e:
+            raise SystemExit(str(e))
         spec, start, state = run.spec, run.start, run.state
         print(f"{cfg.name}: {num_params(state.params):,} params | "
-              f"round n={spec.n} r={spec.r} k={spec.k} {args.schedule}"
+              f"round n={spec.n} r={spec.width} k={spec.k} {args.schedule}"
               f"{'+adaptive' if args.adaptive else ''}"
               f"{' loads=' + ','.join(map(str, loads)) if loads else ''} | "
               f"cluster {args.cluster}"
@@ -356,7 +359,7 @@ def main(argv=None):
                 np.stack(logged_t1), np.stack(logged_t2),
                 meta={"source": "launch.train", "arch": args.arch,
                       "schedule": args.schedule, "cluster": args.cluster,
-                      "n": args.n, "r": spec.r, "k": args.k,
+                      "n": args.n, "r": spec.width, "k": args.k,
                       "seed": args.seed, "start_step": start,
                       "adaptive": bool(args.adaptive)})
             p = save_trace(args.log_delays, trace)

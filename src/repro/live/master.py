@@ -3,16 +3,16 @@ round at ``k`` distinct results (or at the deadline under the configured
 fallback policy), feeding censored arrival feedback to the adaptive
 scheduler, and recording every run as a replayable ``DelayTrace``.
 
-Authoritative statistics come from the ASSEMBLED delay tables, scored with
-the MC engine's own fused arithmetic (``_build_eval`` at the engine's
-``(1, n, r)`` chunk shape): ``s = cumsum(T1) + T2`` (eq. 1), the gather
-plan for per-task arrivals (eq. 2), a rank count for the k-th order statistic.
-Cells never covered by a received message stay +inf — fault-censoring
-semantics, a version-2 trace.  Because the recorded tables are exactly the
-scorer's input, ``sweep_rounds(TraceProcess(result.trace), trials=1)``
-reproduces ``result.per_round`` bit-for-bit for static configs (adaptive
-runs re-derive greedy decisions on replay, so they match in distribution,
-not bitwise).
+Authoritative statistics come from the ASSEMBLED delay tables, scored by
+the one round close (``repro.core.round.make_round_close``) at the
+engine's ``(1, n, r)`` chunk shape: ``s = cumsum(T1) + T2`` (eq. 1), the
+gather plan for per-task arrivals (eq. 2), the k-th order statistic and the
+deadline policy.  Cells never covered by a received message stay +inf —
+fault-censoring semantics, a version-2 trace.  Because the recorded tables
+are exactly the close's input, ``sweep_rounds(TraceProcess(result.trace),
+trials=1)`` reproduces ``result.per_round`` bit-for-bit for static configs
+(adaptive runs re-derive greedy decisions on replay, so they match in
+distribution, not bitwise).
 
 Round-close protocol: the master counts distinct tasks over incoming
 ``result`` messages (under a closing deadline policy, only messages whose
@@ -29,8 +29,10 @@ import dataclasses
 import itertools
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
+from ..core.round import make_round_close, make_scheduler
 from ..core.spec import RoundConfig
 from ..core.trace import DelayTrace
 from .comm import Comm, CommClosedError, Listener, listen
@@ -71,76 +73,6 @@ class LiveResult:
         return float(self.per_round.mean())
 
 
-def _make_scorer(cfg: RoundConfig):
-    """Jitted ``(T1, T2, row_of_worker, loads_w) -> (v, tau, arr_w)`` over
-    one round's machine-major (n, r) tables — the exact arithmetic the MC
-    engine and the trainer's ``StragglerAggregator._round_fn`` run, at the
-    same (1, n, r) chunk shape, so a recorded trace replays bit-exactly.
-
-    ``v`` is the k-th distinct-task arrival (f32 scalar), ``tau`` the
-    per-task arrivals (n,), ``arr_w`` the worker-major per-slot message
-    arrivals (the censored-feedback signal, matching the aggregator's
-    ``arr_w = s2[row_of_worker]``)."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..core import montecarlo as mc
-
-    n, r = cfg.n, cfg.width
-    if cfg.adaptive:
-        base = cfg.base_matrix()
-        if cfg.rebalance:
-            sp_v = mc.to_spec("v", base)
-            sp_tau = mc.tau_spec("tau", base)
-        else:
-            sp_v = mc.to_spec("v", base, messages=cfg.messages,
-                              loads=cfg.loads)
-            sp_tau = mc.tau_spec("tau", base, messages=cfg.messages,
-                                 loads=cfg.loads)
-    else:
-        sp_v = cfg.to_scheme_spec("v")
-        sp_tau = mc.tau_spec("tau", cfg.base_matrix(),
-                             messages=cfg.messages, loads=cfg.loads,
-                             comm_eps=cfg.comm_eps)
-    eval_fn = mc._build_eval((sp_v, sp_tau), n, r, ks=cfg.k)
-    mmap = mc._slot_map_of(sp_v)
-    rebalance = cfg.rebalance
-
-    @jax.jit
-    def _score(T1, T2, row_of_worker, loads_w):
-        # eq. 1 at the engine's (chunk=1, n, r) shape — the identical XLA
-        # program the trace replay runs, so the two agree bit-for-bit
-        s = (jnp.cumsum(T1[None], axis=-1) + T2[None])[0]
-        worker_of_row = jnp.argsort(row_of_worker)
-        s2 = s[worker_of_row]                            # row-major arrivals
-        arr2 = s2 if mmap is None else mc._apply_slot_map(s2, mmap)
-        if rebalance:
-            l_row = loads_w[worker_of_row]
-            live_slots = jnp.arange(r)[None, :] < l_row[:, None]
-            s2 = jnp.where(live_slots, s2, jnp.inf)
-            arr2 = jnp.where(live_slots, arr2, jnp.inf)
-        out = eval_fn(s2[None])
-        return (out["v"][0, -1], out["tau"][0], arr2[row_of_worker])
-
-    return _score
-
-
-def _make_scheduler(cfg: RoundConfig):
-    """The adaptive scheduler exactly as ``StragglerAggregator`` builds it
-    (or None for static schedules)."""
-    if not cfg.adaptive:
-        return None
-    from ..core import scheduling
-    kw = dict(beta=cfg.feedback_beta, gamma=cfg.coverage_gamma)
-    if cfg.dead_after is not None:
-        kw.update(dead_after=cfg.dead_after, target_k=cfg.k)
-    if cfg.rebalance:
-        return scheduling.AdaptiveScheduler(cfg.base_matrix(),
-                                            loads=cfg.loads, rebalance=True,
-                                            **kw)
-    return scheduling.AdaptiveScheduler(cfg.to_matrix(), **kw)
-
-
 async def _pump(w: int, comm: Comm, queue: asyncio.Queue) -> None:
     """Forward every message from worker ``w`` into the central queue;
     ``(w, None)`` marks a dropped connection."""
@@ -172,8 +104,8 @@ class Master:
         self.listener = listener
         self.time_scale = float(time_scale)
         self.abort_on_close = bool(abort_on_close)
-        self.scheduler = _make_scheduler(config)
-        self._score = _make_scorer(config)
+        self.scheduler = make_scheduler(config)
+        self._close = jax.jit(make_round_close(config))
         self._comms: Dict[int, Comm] = {}
 
     async def _handshake(self) -> None:
@@ -289,25 +221,13 @@ class Master:
                     timer.cancel()
 
                 # ---- authoritative stats from the assembled tables ------
-                v_j, tau_j, arr_w = self._score(
-                    T1_tab, T2_tab, np.asarray(row_of_worker),
-                    np.asarray(loads_w))
-                v = np.float32(v_j)
-                tau = np.asarray(tau_j)
-                if closing:                    # mirror engine _policy_close
-                    v_eff = min(v, DL)
-                    by = int((tau <= DL).sum())
-                    realized = min(by, k)
-                    missed = by < k
-                elif DL is not None:           # wait: flag, don't cap
-                    v_eff = v
-                    realized = min(int(np.isfinite(tau).sum()), k)
-                    missed = not (v <= DL)
-                else:
-                    v_eff = v
-                    realized = min(int(np.isfinite(tau).sum()), k)
-                    missed = False
-                per_round[t] = float(v_eff)
+                rc = self._close(T1_tab[None], T2_tab[None],
+                                 np.asarray(row_of_worker),
+                                 np.asarray(loads_w))
+                v_eff = float(rc.t_eff)
+                realized = int(rc.realized)
+                missed = bool(rc.missed)
+                per_round[t] = v_eff
                 realized_a[t] = realized
                 missed_a[t] = missed
                 if self.scheduler is not None:
@@ -317,16 +237,14 @@ class Master:
                         # +inf holes additionally force censoring (a plain
                         # mean over a holey table would pin the EMA at inf)
                         self.scheduler.observe(T1_tab,
-                                               arrivals=np.asarray(arr_w),
-                                               t_done=float(v_eff))
+                                               arrivals=np.asarray(rc.arr_w),
+                                               t_done=v_eff)
                     else:
                         self.scheduler.observe(T1_tab)
                     if cfg.deadline_policy == "reissue":
-                        delivered = ((tau <= np.float32(v_eff))
-                                     & np.isfinite(tau))
-                        self.scheduler.set_need(~delivered)
+                        self.scheduler.set_need(~np.asarray(rc.delivered))
                 reports.append(RoundReport(
-                    round=t, t_done=float(v_eff), realized=realized,
+                    round=t, t_done=v_eff, realized=realized,
                     missed=missed, closed_early=closed, results=n_results,
                     stalled=n_stalled, dead=len(dead)))
             await self._broadcast({"type": SHUTDOWN})

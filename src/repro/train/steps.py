@@ -31,13 +31,9 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..core.aggregator import RoundSpec
 from ..core.cluster import as_process
-from ..core.completion import (apply_row_layout, message_arrival_times,
-                               message_slot_layout, row_layout_is_identity,
-                               winner_mask_gather)
-from ..core.montecarlo import task_arrival_times_gather, task_gather_plan
-from ..core.scheduling import loads_of_matrix
+from ..core.round import make_round_close
+from ..core.spec import RoundConfig
 from ..models import ModelConfig, forward, init_params
 from ..optim import Optimizer, clip_by_global_norm
 from ..sharding import DATA, shard
@@ -116,7 +112,7 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, *,
 
 
 def make_straggler_train_step(cfg: ModelConfig, opt: Optimizer,
-                              round_spec: RoundSpec, delay, *,
+                              round_config: RoundConfig, delay, *,
                               clip_norm: float = 1.0,
                               scan_slots: bool = True):
     """The paper's scheduled round as a jittable SGD step.
@@ -143,34 +139,20 @@ def make_straggler_train_step(cfg: ModelConfig, opt: Optimizer,
     mirrors the paper's sequential per-slot execution; set False to unroll
     (used by the dry-run for exact HLO cost accounting).
 
-    Ragged rounds (``RoundSpec.loads``): rows keep only their first
-    ``loads[i]`` slots — masked slots get +inf arrivals, zero winner
-    weight, and all-zero micro-batches from ``lm_task_batches``, so they
-    contribute nothing to the gradient while the virtual completion time
-    reflects the reduced per-worker loads.  ``RoundSpec.comm_eps`` adds
-    the per-message protocol overhead to every arrival.
+    The round itself is ``repro.core.round``'s round close: ragged
+    ``loads`` give masked slots +inf arrivals, zero winner weight and
+    all-zero micro-batches from ``lm_task_batches``; ``comm_eps`` adds the
+    per-message protocol overhead to every arrival.  Load re-balancing
+    (``rebalance``) changes the slot layout every round, which the step's
+    static (r, n) batches cannot follow, so it is refused.
     """
-    n, r, k = round_spec.n, round_spec.r, round_spec.k
+    if round_config.rebalance:
+        raise ValueError("make_straggler_train_step cannot run rebalance: "
+                         "its slot batches have a static per-row layout")
+    n, r = round_config.n, round_config.width
     process = as_process(delay)
-    base_C = round_spec.to_matrix()          # ragged rows carry their loads
-    plan = task_gather_plan(base_C, n)
-    # a closing deadline (close_partial / reissue) caps the winner
-    # selection at the deadline; "wait" keeps the true completion time
-    dl_close = (round_spec.deadline
-                if round_spec.deadline is not None
-                and round_spec.deadline_policy != "wait" else None)
-    # static per-row message layout: closing-slot remap, per-message
-    # overhead offsets, ragged-load masks.  None when it is the identity
-    # (dense, per-slot sends, no overhead) — the established fast path.
-    _layout = message_slot_layout(loads_of_matrix(base_C), r,
-                                  round_spec.n_messages, round_spec.comm_eps)
-    if row_layout_is_identity(_layout):
-        _layout = None
-
-    def _row_arrivals(s):
-        """Per-message availability in base-row space (rows carry their
-        own grouping/masks whatever worker executes them)."""
-        return s if _layout is None else apply_row_layout(s, _layout)
+    close = make_round_close(round_config)
+    loads_w = round_config.load_vector
 
     def step(state: TrainState, slot_tokens, slot_labels, rng, cluster=None,
              row_of_worker=None, extras=None):
@@ -184,23 +166,10 @@ def make_straggler_train_step(cfg: ModelConfig, opt: Optimizer,
                 jax.random.fold_in(rng, 0x0c10)[None],
                 jnp.zeros((1,), jnp.int32), n)
         cluster, T1, T2 = process.step(cluster, rng[None], n, r)
-        # raw per-slot availability (eq. 1); the message grouping / ragged
-        # masks are applied per row after the (optional) permutation
-        s = message_arrival_times(T1, T2, r)[0]
         if row_of_worker is None:
-            row_arr = _row_arrivals(s)
-            weights, t_done = winner_mask_gather(base_C, plan, row_arr, n, k,
-                                                 deadline=dl_close)
-        else:
-            worker_of_row = jnp.argsort(row_of_worker)       # inverse perm
-            row_arr = _row_arrivals(s[worker_of_row])
-            w2, t_done = winner_mask_gather(base_C, plan, row_arr, n, k,
-                                            deadline=dl_close)
-            weights = w2[row_of_worker]                      # worker-major
-        # per-task delivery by the (capped) round close — feeds the
-        # reissue policy's re-gather priority in the driving loop
-        tau = task_arrival_times_gather(plan, row_arr)
-        delivered = (tau <= t_done) & jnp.isfinite(tau)
+            row_of_worker = jnp.arange(n)
+        rc = close(T1, T2, row_of_worker, loads_w)
+        weights = rc.weights
 
         # realized selected-task count: == k a.s. with per-slot sends, may
         # exceed k when a reduced message budget delivers tasks in lumps —
@@ -241,15 +210,11 @@ def make_straggler_train_step(cfg: ModelConfig, opt: Optimizer,
         updates, opt_state = opt.update(grads, state.opt_state, state.params)
         params = opt.apply(state.params, updates)
         metrics = {"loss": l, "aux": aux, "grad_norm": gnorm,
-                   "completion_time": t_done,
+                   "completion_time": rc.t_eff,
                    "winners": (weights > 0).sum(),
                    "realized_k": wsum_raw,
-                   "delivered_tasks": delivered,
-                   "deadline_missed": (jnp.zeros((), jnp.bool_)
-                                       if round_spec.deadline is None else
-                                       (wsum_raw < k
-                                        if dl_close is not None
-                                        else t_done > round_spec.deadline)),
+                   "delivered_tasks": rc.delivered,
+                   "deadline_missed": rc.missed,
                    "worker_t1": T1[0].mean(axis=-1),
                    # raw per-(worker, slot) delay draws of the round —
                    # what `launch/train.py --log-delays` accumulates into

@@ -11,7 +11,7 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_config, SHAPES, input_specs, \
     shape_supported, long_variant
-from repro.core import RoundSpec, scenario1
+from repro.core import RoundConfig, scenario1
 from repro.data import TaskPartition, lm_task_batches
 from repro.models import (init_params, forward, init_cache, num_params,
                           layer_specs)
@@ -101,7 +101,7 @@ def test_straggler_round_on_reduced_arch(arch):
     cfg = _smoke_cfg(arch)
     opt = adamw(1e-3)
     state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    spec = RoundSpec(n=4, r=2, k=3, schedule="ss")
+    spec = RoundConfig(n=4, r=2, k=3, kind="ss")
     part = TaskPartition(n=4, global_batch=4, seq_len=16,
                          vocab=cfg.vocab_size)
     step = jax.jit(make_straggler_train_step(cfg, opt, spec, scenario1()))

@@ -24,7 +24,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (MASKED, AdaptiveScheduler, DelayTrace,
-                        FAULT_SCENARIOS, IIDProcess, RoundSpec,
+                        FAULT_SCENARIOS, IIDProcess, RoundConfig,
                         TraceProcess, adaptive_spec, cyclic_to_matrix,
                         load_trace, make_scenario, save_trace, scenario1,
                         sweep_rounds, to_spec, trajectory_samples,
@@ -274,15 +274,15 @@ def test_engine_rejects_uncoverable_schedule():
 
 def test_roundspec_deadline_validation():
     with pytest.raises(ValueError, match="needs a deadline"):
-        RoundSpec(n=N, r=R, k=K, schedule="cs",
-                  deadline_policy="close_partial")
+        RoundConfig(n=N, r=R, k=K, kind="cs",
+                    deadline_policy="close_partial")
     with pytest.raises(ValueError, match="deadline_policy"):
-        RoundSpec(n=N, r=R, k=K, schedule="cs", deadline=1.0,
-                  deadline_policy="eventually")
+        RoundConfig(n=N, r=R, k=K, kind="cs", deadline=1.0,
+                    deadline_policy="eventually")
     with pytest.raises(ValueError, match="deadline must be"):
-        RoundSpec(n=N, r=R, k=K, schedule="cs", deadline=0.0)
-    spec = RoundSpec(n=N, r=R, k=K, schedule="cs", deadline=1.0,
-                     deadline_policy="reissue")
+        RoundConfig(n=N, r=R, k=K, kind="cs", deadline=0.0)
+    spec = RoundConfig(n=N, r=R, k=K, kind="cs", deadline=1.0,
+                       deadline_policy="reissue", adaptive=True)
     assert spec.deadline == 1.0
 
 

@@ -261,10 +261,10 @@ def test_censored_adaptive_still_beats_static():
 
 
 def test_censored_feedback_requires_adaptive_aggregator():
-    from repro.core import RoundSpec, StragglerAggregator
+    from repro.core import RoundConfig, StragglerAggregator
     with pytest.raises(ValueError, match="adaptive"):
-        StragglerAggregator(RoundSpec(n=6, r=3, k=4), scenario1(),
-                            censored_feedback=True)
+        StragglerAggregator(RoundConfig(n=6, r=3, k=4, kind="ss",
+                                        censored_feedback=True), scenario1())
 
 
 def test_censored_rounds_chunk_invariant():

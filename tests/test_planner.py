@@ -189,8 +189,7 @@ class TestRebalanceMessages:
         def _agg(m):
             cfg = RoundConfig(n=N, k=N, kind="cs", r=4, loads=(2,) * N,
                               messages=m, adaptive=True, rebalance=True)
-            return StragglerAggregator(cfg.to_round_spec(), MODEL,
-                                       adaptive=True, rebalance=True)
+            return StragglerAggregator(cfg, MODEL)
         agg = _agg(2)
         ts = [float(agg.round_mask(jax.random.PRNGKey(i))[1])
               for i in range(3)]

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from repro.core import (MASKED, AdaptiveScheduler, MarkovRegimeProcess,
-                        RoundSpec, ShiftedExponentialDelays,
+                        RoundConfig, ShiftedExponentialDelays,
                         StragglerAggregator, adaptive_spec, clear_cache,
                         completion_samples, cyclic_to_matrix, ec2_cluster,
                         greedy_load_rebalance, greedy_load_rebalance_batch,
@@ -475,7 +475,7 @@ class TestRebalanceRounds:
 
 class TestRaggedAggregator:
     def test_ragged_round_weights(self):
-        spec = RoundSpec(n=6, r=3, k=4, schedule="ss", loads=LOADS)
+        spec = RoundConfig(n=6, r=3, k=4, kind="ss", loads=LOADS)
         agg = StragglerAggregator(spec, scenario1())
         C = agg.current_matrix()
         assert (loads_of_matrix(C) == np.asarray(LOADS)).all()
@@ -487,10 +487,11 @@ class TestRaggedAggregator:
         np.testing.assert_allclose(np.asarray(out["g"]), 1.0, rtol=1e-5)
 
     def test_rebalance_round_api(self):
-        spec = RoundSpec(n=8, r=5, k=6, schedule="cs", loads=(2,) * 8)
+        spec = RoundConfig(n=8, r=5, k=6, kind="cs", loads=(2,) * 8,
+                           adaptive=True, censored_feedback=True,
+                           rebalance=True)
         proc = ec2_cluster(8, spread=3.0, persistence=0.95, slow=10.0)
-        agg = StragglerAggregator(spec, proc, adaptive=True,
-                                  censored_feedback=True, rebalance=True)
+        agg = StragglerAggregator(spec, proc)
         for i in range(4):
             C = agg.current_matrix()
             validate_to_matrix(C, 8)
@@ -503,18 +504,21 @@ class TestRaggedAggregator:
     def test_rebalance_requires_adaptive_and_budget(self):
         m = scenario1()
         with pytest.raises(ValueError, match="adaptive"):
-            StragglerAggregator(RoundSpec(n=4, r=2, k=3, loads=(1,) * 4), m,
-                                rebalance=True)
+            StragglerAggregator(RoundConfig(n=4, r=2, k=3, kind="ss",
+                                            loads=(1,) * 4, rebalance=True),
+                                m)
         with pytest.raises(ValueError, match="budget"):
-            StragglerAggregator(RoundSpec(n=4, r=2, k=3), m, adaptive=True,
-                                rebalance=True)
+            StragglerAggregator(RoundConfig(n=4, r=2, k=3, kind="ss",
+                                            adaptive=True, rebalance=True),
+                                m)
 
     def test_roundspec_loads_validation(self):
         with pytest.raises(ValueError, match="loads"):
-            RoundSpec(n=4, r=2, k=3, loads=(3, 1, 1, 1))   # load > r
+            RoundConfig(n=4, r=2, k=3, kind="ss",
+                        loads=(3, 1, 1, 1))                # load > r
         with pytest.raises(ValueError, match="diagonal"):
-            RoundSpec(n=4, r=2, k=3, schedule="block", loads=(2, 1, 1, 2))
-        spec = RoundSpec(n=4, r=2, k=3, schedule="cs", loads=[2, 1, 1, 2])
+            RoundConfig(n=4, r=2, k=3, kind="block", loads=(2, 1, 1, 2))
+        spec = RoundConfig(n=4, r=2, k=3, kind="cs", loads=[2, 1, 1, 2])
         assert spec.loads == (2, 1, 1, 2)                  # canonical tuple
         assert (spec.load_vector == [2, 1, 1, 2]).all()
 

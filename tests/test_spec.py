@@ -1,17 +1,14 @@
 """RoundConfig — the one canonical round validator + its JSON form and
-legacy (SchemeSpec / RoundSpec) derivations.  Covers validation parity with
-the legacy constructors, adaptive-family cross-field rules, the deprecation
-shims, and config <-> JSON <-> config round-trips."""
-import warnings
-
+the engine's SchemeSpec derivation.  Covers validation, adaptive-family
+cross-field rules, the engine record's matrix, and config <-> JSON <->
+config round-trips."""
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (DEADLINE_POLICIES, RoundConfig, RoundSpec,
-                        ec2_cluster, sweep_rounds, validate_deadline)
-from repro.core.montecarlo import SchemeSpec, adaptive_spec, to_spec
-from repro.core.spec import _reset_legacy_warnings
+from repro.core import (DEADLINE_POLICIES, RoundConfig, ec2_cluster,
+                        sweep_rounds, validate_deadline)
+from repro.core.montecarlo import adaptive_spec, to_spec
 
 
 class TestValidation:
@@ -110,8 +107,8 @@ class TestValidation:
 
 
 class TestLegacyParity:
-    """RoundConfig and the legacy constructors accept/reject the same
-    configurations and derive bit-identical objects."""
+    """RoundConfig rejects invalid rounds, and the engine's record of a
+    round sees the same matrix as the round close."""
 
     @pytest.mark.parametrize("kw", [
         dict(n=4, k=5, r=2),
@@ -123,12 +120,6 @@ class TestLegacyParity:
     def test_both_reject(self, kw):
         with pytest.raises(ValueError):
             RoundConfig(**kw)
-        legacy = dict(kw)
-        legacy["schedule"] = legacy.pop("kind", "cs")
-        legacy.setdefault("r", legacy["n"])
-        with pytest.raises(ValueError), warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            RoundSpec(**legacy)
 
     @pytest.mark.parametrize("kw", [
         dict(n=5, k=3, kind="cs", r=2),
@@ -139,16 +130,8 @@ class TestLegacyParity:
     ])
     def test_matrices_match_legacy(self, kw):
         cfg = RoundConfig(**kw)
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            spec = RoundSpec(n=cfg.n, r=cfg.width, k=cfg.k,
-                             schedule=cfg.kind, seed=cfg.seed,
-                             messages=cfg.messages, loads=cfg.loads,
-                             comm_eps=cfg.comm_eps)
-        np.testing.assert_array_equal(cfg.to_matrix(), spec.to_matrix())
-        rt = cfg.to_round_spec()
-        assert rt == spec
-        np.testing.assert_array_equal(rt.to_matrix(), cfg.to_matrix())
+        np.testing.assert_array_equal(cfg.to_scheme_spec("s").matrix(),
+                                      cfg.to_matrix())
 
     def test_scheme_spec_matches_factories(self):
         cfg = RoundConfig(n=5, k=3, kind="cs", r=3, loads=(1, 2, 3, 2, 1),
@@ -180,35 +163,7 @@ class TestLegacyParity:
         assert kw["k"] == 3 and kw["deadline"] == 1.5
         assert kw["deadline_policy"] == "close_partial"
         assert kw["feedback_beta"] == 0.6 and kw["censored_feedback"]
-        ak = cfg.aggregator_kwargs()
-        assert ak["adaptive"] and ak["dead_after"] == 2
-        assert ak["coverage_gamma"] == 0.4
-
-
-class TestDeprecationShims:
-    def test_legacy_constructors_warn_once(self):
-        _reset_legacy_warnings()
-        with pytest.warns(DeprecationWarning, match="RoundConfig"):
-            RoundSpec(n=4, r=2, k=3)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", DeprecationWarning)
-            RoundSpec(n=4, r=2, k=3)          # second build: silent
-        _reset_legacy_warnings()
-        with pytest.warns(DeprecationWarning, match="RoundConfig"):
-            SchemeSpec(name="x", kind="to", C=((0, 1), (1, 0)))
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", DeprecationWarning)
-            SchemeSpec(name="x", kind="to", C=((0, 1), (1, 0)))
-
-    def test_internal_paths_never_warn(self):
-        _reset_legacy_warnings()
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", DeprecationWarning)
-            to_spec("s", [[0, 1], [1, 0]])
-            adaptive_spec("a", [[0, 1], [1, 0]])
-            cfg = RoundConfig(n=4, k=3, r=2)
-            cfg.to_round_spec()
-            cfg.to_scheme_spec()
+        assert kw["coverage_gamma"] == 0.4
 
 
 class TestJSONRoundTrip:

@@ -22,7 +22,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (AR1Process, CalibrationReport, DelayTrace,
-                        IIDProcess, MarkovRegimeProcess, RoundSpec,
+                        IIDProcess, MarkovRegimeProcess, RoundConfig,
                         StragglerAggregator, TraceProcess, adaptive_spec,
                         as_process, calibrate_trace, cyclic_to_matrix,
                         ec2_cluster, lb_spec, load_trace, save_trace,
@@ -294,7 +294,7 @@ def test_calibration_homogeneous_degenerates_gracefully():
 
 def test_aggregator_replays_trace_deterministically():
     tr = _small_trace(rounds=4, trials=1, n=4, r=2, seed=5)
-    spec = RoundSpec(n=4, r=2, k=3, schedule="ss")
+    spec = RoundConfig(n=4, r=2, k=3, kind="ss")
 
     def run():
         agg = StragglerAggregator(spec, tr)        # DelayTrace coerced

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (RoundSpec, scenario1, cyclic_to_matrix, ec2_cluster,
+from repro.core import (RoundConfig, scenario1, cyclic_to_matrix, ec2_cluster,
                         greedy_row_assignment)
 from repro.data import TaskPartition, lm_task_batches, bigram_tokens
 from repro.models import ModelConfig, init_cache
@@ -59,7 +59,7 @@ class TestStragglerStep:
     def test_loss_decreases_and_metrics(self):
         opt = adamw(1e-2, weight_decay=0.0)
         state = init_train_state(jax.random.PRNGKey(0), CFG, opt)
-        spec = RoundSpec(n=4, r=2, k=3, schedule="cs")
+        spec = RoundConfig(n=4, r=2, k=3, kind="cs")
         part = TaskPartition(n=4, global_batch=8, seq_len=16,
                              vocab=64, source="bigram")
         step = jax.jit(make_straggler_train_step(CFG, opt, spec, scenario1()))
@@ -78,7 +78,7 @@ class TestStragglerStep:
     def test_k_equals_n_uses_all_tasks(self):
         opt = sgd(1e-2)
         state = init_train_state(jax.random.PRNGKey(0), CFG, opt)
-        spec = RoundSpec(n=4, r=2, k=4)
+        spec = RoundConfig(n=4, r=2, k=4, kind="ss")
         part = TaskPartition(n=4, global_batch=4, seq_len=8, vocab=64)
         step = jax.jit(make_straggler_train_step(CFG, opt, spec, scenario1()))
         toks, labs = lm_task_batches(part, spec.to_matrix(), 0)
@@ -89,7 +89,7 @@ class TestStragglerStep:
         """r=1, k=n: every task used exactly once -> gradient equals the
         plain full-batch step (same data, same init)."""
         opt = sgd(0.1)
-        spec = RoundSpec(n=4, r=1, k=4, schedule="cs")
+        spec = RoundConfig(n=4, r=1, k=4, kind="cs")
         part = TaskPartition(n=4, global_batch=4, seq_len=8, vocab=64)
         C = spec.to_matrix()
         toks, labs = lm_task_batches(part, C, 0)
@@ -119,7 +119,7 @@ class TestStragglerStep:
         per-worker delays stay correlated across consecutive rounds."""
         opt = sgd(1e-2)
         state = init_train_state(jax.random.PRNGKey(0), CFG, opt)
-        spec = RoundSpec(n=4, r=2, k=3, schedule="cs")
+        spec = RoundConfig(n=4, r=2, k=3, kind="cs")
         proc = ec2_cluster(4, spread=2.0, p_slow=0.5, persistence=0.98,
                            slow=50.0)
         part = TaskPartition(n=4, global_batch=8, seq_len=16, vocab=64)
@@ -145,7 +145,7 @@ class TestStragglerStep:
         exactly; a nontrivial permutation with matching data keeps the
         winner count at k."""
         opt = sgd(1e-2)
-        spec = RoundSpec(n=4, r=2, k=3, schedule="cs")
+        spec = RoundConfig(n=4, r=2, k=3, kind="cs")
         part = TaskPartition(n=4, global_batch=8, seq_len=16, vocab=64)
         step = jax.jit(make_straggler_train_step(CFG, opt, spec, scenario1()))
         C = spec.to_matrix()
@@ -169,7 +169,7 @@ class TestStragglerStep:
         """eq. (61): with k < n the estimator scales by n/k — the expected
         gradient over delay randomness equals the full-data gradient.
         Verified by averaging the weighted loss value over many rounds."""
-        spec = RoundSpec(n=6, r=6, k=3, schedule="cs")
+        spec = RoundConfig(n=6, r=6, k=3, kind="cs")
         part = TaskPartition(n=6, global_batch=6, seq_len=8, vocab=64)
         C = spec.to_matrix()
         toks, labs = lm_task_batches(part, C, 0)
